@@ -25,6 +25,7 @@ def run(n_entries: int = 1 << 22, batch: int = 256, depth: int = 64) -> dict:
     import jax.numpy as jnp
 
     from repro.analysis.hlo import analyze_hlo
+    from repro.compat import make_mesh
     from repro.sharding.compute_to_data import (
         chase_oracle,
         dapc_shard_map,
@@ -34,7 +35,7 @@ def run(n_entries: int = 1 << 22, batch: int = 256, depth: int = 64) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_mesh((1, n_dev), ("data", "model"))
     rng = np.random.default_rng(0)
     perm = rng.permutation(n_entries)
     table = np.empty(n_entries, np.int32)

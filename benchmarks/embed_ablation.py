@@ -20,10 +20,11 @@ def run(vocab: int = 32_768, d_model: int = 256, batch: int = 8, seq: int = 128)
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.analysis.hlo import analyze_hlo
+    from repro.compat import make_mesh
     from repro.models.embedding import embed_c2d, embed_gather, embed_auto
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_mesh((1, n_dev), ("data", "model"))
     table_sh = NamedSharding(mesh, P("model", None))
     ids_sh = NamedSharding(mesh, P(None, None))
     sds = jax.ShapeDtypeStruct

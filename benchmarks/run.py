@@ -5,14 +5,15 @@
   dapc_tensor  the compiled-SPMD rendering of the same experiment
   roofline     summary of the dry-run artifact table (if present)
 
-Writes artifacts/bench.json and prints a compact CSV per benchmark.
+Every section runs in this process over ``jax.devices()`` (a chip belongs
+to one process); a failed section fails the run.  Writes
+artifacts/bench.json and prints a compact CSV per benchmark.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -129,44 +130,19 @@ def bench_gather(fast: bool = False) -> dict:
 
 
 def bench_dapc_tensor() -> dict:
-    # needs >1 device: run in a subprocess with 8 host platform devices
-    import subprocess
+    from .dapc_tensor import run
 
-    code = (
-        "import os; os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=8';"
-        "import json; from benchmarks.dapc_tensor import run;"
-        "print(json.dumps(run(), default=float))"
-    )
-    env = dict(os.environ, PYTHONPATH="src")
-    r = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        cwd=Path(__file__).resolve().parent.parent, timeout=600,
-    )
-    out = json.loads(r.stdout.strip().splitlines()[-1]) if r.returncode == 0 else {
-        "error": r.stderr[-800:]
-    }
-    _section("DAPC tensor-scale (compiled SPMD, 8 devices)")
+    out = run()
+    _section(f"DAPC tensor-scale (compiled SPMD, {out['devices']} devices)")
     print(json.dumps(out, indent=1, default=float))
     return out
 
 
 def bench_embed_ablation() -> dict:
-    import subprocess
+    from .embed_ablation import run
 
-    code = (
-        "import os; os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=8';"
-        "import json; from benchmarks.embed_ablation import run;"
-        "print(json.dumps(run(), default=float))"
-    )
-    env = dict(os.environ, PYTHONPATH="src")
-    r = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        cwd=Path(__file__).resolve().parent.parent, timeout=600,
-    )
-    out = json.loads(r.stdout.strip().splitlines()[-1]) if r.returncode == 0 else {
-        "error": r.stderr[-800:]
-    }
-    _section("Embedding ablation: c2d vs gather vs auto (8 devices)")
+    out = run()
+    _section(f"Embedding ablation: c2d vs gather vs auto ({out['devices']} devices)")
     print(json.dumps(out, indent=1, default=float))
     return out
 

@@ -51,6 +51,7 @@ def compiled_rendering(tiny: bool) -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.compat import make_mesh
     from repro.sharding.compute_to_data import gather_ref, gather_shard_map
 
     print("\n== compiled SPMD rendering (steady state: keys move, rows psum) ==")
@@ -58,7 +59,7 @@ def compiled_rendering(tiny: bool) -> None:
     rng = np.random.default_rng(2)
     table = rng.standard_normal((vocab, dim)).astype(np.float32)
     keys = rng.integers(0, vocab, b).astype(np.int32)
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_mesh((1, jax.device_count()), ("data", "model"))
     got = np.asarray(
         gather_shard_map(jnp.asarray(table), jnp.asarray(keys), mesh)
     )

@@ -46,6 +46,7 @@ def compiled_rendering(tiny: bool) -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.compat import make_mesh
     from repro.kernels.chase.kernel import chase_shard
     from repro.sharding.compute_to_data import chase_oracle, dapc_shard_map
 
@@ -56,7 +57,7 @@ def compiled_rendering(tiny: bool) -> None:
     table = np.empty(n, np.int32)
     table[perm] = np.roll(perm, -1)
     starts = rng.integers(0, n, b).astype(np.int32)
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_mesh((1, jax.device_count()), ("data", "model"))
     got = np.asarray(dapc_shard_map(jnp.asarray(table), jnp.asarray(starts), depth, mesh))
     want = chase_oracle(table, starts, depth)
     assert np.array_equal(got, want)

@@ -25,16 +25,23 @@ import jax.export
 
 from .frame import CorruptFrame
 
-# Target triples. ``platform`` is what jax.export lowers for; ``mcpu`` models
-# the micro-architecture field the paper optimizes for on the target (A64FX
-# SVE vs. Xeon AVX2). On this container only the cpu slice is *executable*,
-# but tpu slices are still *generated* (cross-lowering), exactly like the
-# paper generating AArch64 bitcode on a Xeon.
+# Target triples. ``platform`` is what jax.export lowers for and which JAX
+# backend runs the slice; the suffix models the micro-architecture the paper
+# optimizes for on the target (A64FX SVE vs. Xeon AVX2).  Every slice is
+# generated on any machine (cross-lowering, like the paper generating
+# AArch64 bitcode on a Xeon); a slice runs only in a process that has a
+# device of its platform (see :func:`device_of`).
 _TRIPLE_PLATFORM: dict[str, str] = {
     "cpu-host": "cpu",
     "cpu-a64fx": "cpu",
     "cpu-bf2": "cpu",
     "tpu-v5e": "tpu",
+}
+
+# ``jax.Device.device_kind`` -> the triple of a PE running on that device
+_KIND_TRIPLE: dict[str, str] = {
+    "cpu": "cpu-host",
+    "TPU v5 lite": "tpu-v5e",
 }
 
 DEFAULT_TOOLCHAIN_TARGETS: tuple[str, ...] = ("cpu-host", "tpu-v5e")
@@ -49,10 +56,26 @@ def platform_of(triple: str) -> str:
         raise ValueError(f"unknown target triple: {triple!r}") from None
 
 
+def device_of(triple: str) -> jax.Device:
+    """The device a PE of ``triple`` computes on: the first device of the
+    triple's platform.  Raises when this process has no such device — a
+    ``tpu-v5e`` PE never silently runs on the host CPU."""
+    plat = platform_of(triple)
+    try:
+        return jax.devices(plat)[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            f"no {plat!r} device in this process for a {triple!r} PE: {e}"
+        ) from None
+
+
 def local_triple() -> str:
-    """The triple of the processing element we are running on."""
-    plat = jax.default_backend()
-    return "cpu-host" if plat == "cpu" else "tpu-v5e"
+    """The triple of the default device this process runs on."""
+    kind = jax.devices()[0].device_kind
+    try:
+        return _KIND_TRIPLE[kind]
+    except KeyError:
+        raise ValueError(f"no target triple for device kind {kind!r}") from None
 
 
 @dataclass(frozen=True)
@@ -96,26 +119,15 @@ class FatBitcode:
         A triple key wins over its platform key (both map to the same
         lowering platform — the BF2's Arm cores are still ``"cpu"`` to
         XLA, but its slice may carry a different body).  Every slice must
-        compute the same function; only the lowering differs.  A slice
-        whose override fails to cross-lower (e.g. a Pallas TPU kernel that
-        this JAX build cannot serialize from a CPU-only machine) falls
-        back to the portable ``fn``.
+        compute the same function; only the lowering differs.  A body that
+        fails to lower fails the build.
         """
         slices: dict[str, bytes] = {}
         overrides = dict(fn_by_platform or {})
         for triple in targets:
             plat = platform_of(triple)
             entry = overrides.get(triple, overrides.get(plat, fn))
-            try:
-                exported = jax.export.export(
-                    jax.jit(entry), platforms=[plat]
-                )(*in_avals)
-            except Exception:
-                if entry is fn:
-                    raise
-                exported = jax.export.export(jax.jit(fn), platforms=[plat])(
-                    *in_avals
-                )
+            exported = jax.export.export(jax.jit(entry), platforms=[plat])(*in_avals)
             slices[triple] = exported.serialize()
         return cls(slices=slices)
 
@@ -192,25 +204,3 @@ class FatBitcode:
     def triples(self) -> tuple[str, ...]:
         return tuple(sorted(self.slices))
 
-
-def deserialize_and_jit(blob: bytes) -> tuple[Callable[..., Any], tuple]:
-    """Target-side ORC-JIT analogue: deserialize a slice and wrap in jit.
-
-    Returns (compiled callable, in_avals). The first invocation pays XLA
-    compile (the paper's ms-scale JIT cost); subsequent calls hit XLA's
-    executable cache, which is what :class:`repro.core.cache.TargetCodeCache`
-    keeps alive across messages.
-    """
-    exported = jax.export.deserialize(blob)
-    return jax.jit(exported.call), tuple(exported.in_avals)
-
-
-def deserialize_eager(blob: bytes) -> tuple[Callable[..., Any], tuple]:
-    """Binary-mode analogue: code arrives ready-to-run, no target JIT.
-
-    Mirrors binary ifuncs (Sec. III-B): zero compile latency on target but no
-    target-µarch optimization. The call goes through the deserialized
-    executable without an outer jit wrapper.
-    """
-    exported = jax.export.deserialize(blob)
-    return exported.call, tuple(exported.in_avals)

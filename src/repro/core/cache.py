@@ -14,7 +14,7 @@
   invoke. Also remembers which ifunc *names* are registered, which is how the
   receiver decides whether to expect a truncated or a full frame.  The
   batched runtime additionally caches one *batched* executable per
-  (digest, padding bucket): a vmapped/`lax.map`-ped rendering of the same
+  (digest, padding bucket): a `lax.map`-ped rendering of the same
   code that retires a whole (B, ...) payload block in one XLA dispatch.
 """
 

@@ -5,9 +5,10 @@ Target side of Sec. III-C/D: extract the triple's slice from a fat-bitcode
 archive -> (ORC-)JIT -> digest cache, with the name registry deciding
 whether a truncated (digest-only) frame is acceptable and the digest
 deciding whether a name's code is *current*.  The batched renderings —
-``vmap``/``lax.map`` for value ABIs, the masked ``lax.scan`` fold for
+``lax.map`` for value ABIs, the masked ``lax.scan`` fold for
 update/propagate ABIs — are cached per (digest, power-of-two bucket) in
-the same :class:`repro.core.cache.TargetCodeCache`.
+the same :class:`repro.core.cache.TargetCodeCache`.  Every executable is
+compiled for the PE's own device.
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import SingleDeviceSharding
 
 from ..bitcode import FatBitcode
 from ..cache import CachedExecutable, TargetCodeCache
@@ -33,13 +35,23 @@ class CodeCacheLayer:
     """Install/resolve/batch-compile for one PE's target code cache."""
 
     def __init__(
-        self, name: str, triple: str, cache: TargetCodeCache, stats, verifier=None
+        self,
+        name: str,
+        triple: str,
+        cache: TargetCodeCache,
+        stats,
+        device: jax.Device,
+        verifier=None,
     ) -> None:
         self.name = name
         self.triple = triple
         self.cache = cache
         self.stats = stats  # the PE's PEStats (shared across layers)
+        self.sharding = SingleDeviceSharding(device)  # where code compiles for
         self.verifier = verifier  # the PE's Verifier (None in bare tests)
+
+    def _on_device(self, shape, dtype) -> jax.ShapeDtypeStruct:
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.sharding)
 
     def _gate(self, name, digest_hex, deps, exported, admitted_ttl=None) -> None:
         """Run the install-time verifier over one code-cache ingress.  A
@@ -98,7 +110,8 @@ class CodeCacheLayer:
         # cost this PE an XLA compilation (the compile itself is a resource)
         self._gate(frame.name, frame.digest.hex(), frame.deps, exported, admitted_ttl)
         t0 = time.perf_counter()
-        compiled = jax.jit(exported.call).lower(*exported.in_avals).compile()
+        avals = [self._on_device(a.shape, a.dtype) for a in exported.in_avals]
+        compiled = jax.jit(exported.call).lower(*avals).compile()
         jit_ms = (time.perf_counter() - t0) * 1e3
         abi = "pure"
         for d in frame.deps:
@@ -211,15 +224,15 @@ class CodeCacheLayer:
         return 1 << max(0, n - 1).bit_length()
 
     def batched_executable(self, exe: CachedExecutable, bucket: int):
-        """The vmapped rendering of an installed ifunc, cached per
+        """The batched rendering of an installed ifunc, cached per
         (digest, bucket) in the target code cache.
 
-        ``jax.vmap`` over a deserialized export blob needs a batching rule
-        for ``call_exported``; where the installed JAX version lacks one,
-        the fallback is ``lax.map`` — sequential semantics inside ONE fused
-        XLA dispatch, which is the quantity being amortized.  update-ABI
-        code folds payloads into the region carry with a masked ``lax.scan``
-        (exact sequential semantics, one dispatch, one region write).
+        Value ABIs run ``lax.map`` over the payload block — sequential
+        semantics inside ONE fused XLA dispatch, which is the quantity
+        being amortized (``jax.vmap`` has no batching rule for
+        ``call_exported``).  update-ABI code folds payloads into the region
+        carry with a masked ``lax.scan`` (exact sequential semantics, one
+        dispatch, one region write).
         """
         hit = self.cache.lookup_batched(exe.digest, bucket)
         if hit is not None:
@@ -228,8 +241,8 @@ class CodeCacheLayer:
         call = exported.call
         abi = exe.extras.get("abi", "pure")
         pay_aval = exe.in_avals[0]
-        block_aval = jax.ShapeDtypeStruct((bucket, *pay_aval.shape), pay_aval.dtype)
-        dep_avals = tuple(exe.in_avals[1:])
+        block_aval = self._on_device((bucket, *pay_aval.shape), pay_aval.dtype)
+        dep_avals = tuple(self._on_device(a.shape, a.dtype) for a in exe.in_avals[1:])
         t0 = time.perf_counter()
         if abi in ("update", "propagate"):
             # entry(payload, ..region.., ...) -> new_region (update) or
@@ -237,7 +250,7 @@ class CodeCacheLayer:
             # padded rows are masked out so the fold is exact — a masked
             # propagate row contributes neither to the region nor an action
             # (its row is overwritten with NOPs).
-            valid_aval = jax.ShapeDtypeStruct((bucket,), jnp.bool_)
+            valid_aval = self._on_device((bucket,), jnp.bool_)
             rpos = region_arg_pos(exe)
 
             def folded(pays, valid, region, *extra):
@@ -261,22 +274,10 @@ class CodeCacheLayer:
                 .compile()
             )
         else:
-            def vmapped(pays, *deps):
-                return jax.vmap(call, in_axes=(0, *([None] * len(dep_avals))))(
-                    pays, *deps
-                )
-
             def mapped(pays, *deps):
                 return lax.map(lambda p: call(p, *deps), pays)
 
-            compiled = None
-            for impl in (vmapped, mapped):
-                try:
-                    compiled = jax.jit(impl).lower(block_aval, *dep_avals).compile()
-                    break
-                except NotImplementedError:
-                    continue
-            assert compiled is not None
+            compiled = jax.jit(mapped).lower(block_aval, *dep_avals).compile()
         self.stats.jit_ms_total += (time.perf_counter() - t0) * 1e3
         self.cache.install_batched(exe.digest, bucket, compiled)
         return compiled

@@ -26,7 +26,7 @@ from typing import Callable, Sequence
 import jax
 import numpy as np
 
-from ..bitcode import platform_of
+from ..bitcode import device_of, platform_of
 from ..cache import CachedExecutable, SenderCache, TargetCodeCache
 from ..dataplane import DataPlaneConfig
 from ..frame import Frame, FrameFlags, FrameKind, HopHeader, ProtocolError, pack_hop
@@ -126,10 +126,15 @@ class PE:
     """A processing element: endpoint + layered ifunc runtime + local state.
 
     ``triple`` models the ISA/uarch (hosts are ``cpu-host`` Xeons, DPUs are
-    ``cpu-bf2`` BlueField Arm cores, A64FX nodes ``cpu-a64fx``); on this
-    container all execute on the CPU backend, but triple *mismatch logic* is
-    real: binary ifuncs require an exact triple, fat-bitcode falls back by
-    platform and re-optimizes locally (Sec. III-C).
+    ``cpu-bf2`` BlueField Arm cores, A64FX nodes ``cpu-a64fx``, TPU chips
+    ``tpu-v5e``) and names the device the PE computes on: the first device
+    of the triple's platform (:func:`repro.core.bitcode.device_of`).
+    ``cpu-*`` PEs run on the host CPU, a ``tpu-v5e`` PE on the chip — and
+    raises at construction in a process without one.  Installed code is
+    compiled for that device and regions are placed there.  Triple
+    *mismatch logic* is real: binary ifuncs require an exact triple,
+    fat-bitcode falls back by platform and re-optimizes locally (Sec.
+    III-C).
 
     Runtime knobs (all default to the pre-layered behaviour):
 
@@ -151,7 +156,7 @@ class PE:
         toolchain: Toolchain | None = None,
         peers: Sequence[str] = (),
     ) -> None:
-        platform_of(triple)  # validate
+        self.device = device_of(triple)
         self.name = name
         self.triple = triple
         self.fabric = fabric
@@ -184,7 +189,7 @@ class PE:
             name, fabric, self.endpoint, self.sender_cache, self.stats, self.peers
         )
         self.codecache = CodeCacheLayer(
-            name, triple, self.target_cache, self.stats, self.verifier
+            name, triple, self.target_cache, self.stats, self.device, self.verifier
         )
         self.execl = ExecLayer(self, self.codecache, self.stats, self.verifier)
         self.progress = ProgressEngine(
@@ -338,7 +343,7 @@ class PE:
         hit = self._region_dev.get(name)
         if hit is not None and hit[0] == ver:
             return hit[1]
-        dev = jax.device_put(self.endpoint.regions[name])
+        dev = jax.device_put(self.endpoint.regions[name], self.device)
         self._region_dev[name] = (ver, dev)
         return dev
 

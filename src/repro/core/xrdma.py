@@ -257,18 +257,14 @@ def make_gatherer(
 
     fn_by_platform = None
     # the TPU slice carries the Pallas one-hot-MXU resolver when the shard
-    # shape satisfies its blocking constraints; FatBitcode.build falls back
-    # to the portable entry if the kernel cannot cross-lower from here
+    # shape satisfies its blocking constraints (portable entry otherwise)
     if pallas_tpu and (rows_per_shard <= 512 or rows_per_shard % 512 == 0):
-        try:
-            from repro.kernels.embed_lookup.kernel import embed_lookup
+        from repro.kernels.embed_lookup.kernel import embed_lookup
 
-            def pallas_resolve(shard, keys, lo):
-                return embed_lookup(shard, keys, lo, bt=min(256, K))
+        def pallas_resolve(shard, keys, lo):
+            return embed_lookup(shard, keys, lo, bt=min(256, K))
 
-            fn_by_platform = {"tpu": entry_with(pallas_resolve)}
-        except Exception:
-            fn_by_platform = None
+        fn_by_platform = {"tpu": entry_with(pallas_resolve)}
 
     return IFunc.build(
         name=name,
@@ -481,19 +477,15 @@ def make_filter(
 
     fn_by_platform: dict = {"cpu-bf2": entry_with(masked_take_resolve)}
     # the TPU slice carries the Pallas resolver under the same blocking
-    # constraints as the Gatherer; FatBitcode.build falls back to the
-    # portable sliced entry if the kernel cannot cross-lower from here
+    # constraints as the Gatherer (portable sliced entry otherwise)
     if pallas_tpu and (rows_per_shard <= 512 or rows_per_shard % 512 == 0):
-        try:
-            from repro.kernels.embed_lookup.kernel import embed_lookup
+        from repro.kernels.embed_lookup.kernel import embed_lookup
 
-            def pallas_resolve(shard, lo, base):
-                keys = lo + jnp.arange(W, dtype=I32)
-                return embed_lookup(shard, keys, base, bt=min(256, W))
+        def pallas_resolve(shard, lo, base):
+            keys = lo + jnp.arange(W, dtype=I32)
+            return embed_lookup(shard, keys, base, bt=min(256, W))
 
-            fn_by_platform["tpu"] = entry_with(pallas_resolve)
-        except Exception:
-            pass
+        fn_by_platform["tpu"] = entry_with(pallas_resolve)
 
     return IFunc.build(
         name=name,

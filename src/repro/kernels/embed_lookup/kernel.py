@@ -37,8 +37,12 @@ def _embed_kernel(lo_ref, ids_ref, tab_ref, o_ref, acc_scr, *, bv: int, nv: int)
     # one-hot in registers: (BT, BV)
     cols = base + jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], bv), 1)
     onehot = (ids[:, None] == cols).astype(tab.dtype)
+    # an f32 table is contracted at f32 precision so each row comes back
+    # bit-exact (Mosaic refuses the attribute on bf16 operands)
+    precision = jax.lax.Precision.HIGHEST if tab.dtype == jnp.float32 else None
     acc_scr[...] += jax.lax.dot_general(
-        onehot, tab, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, tab, (((1,), (0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32,
     )
 
     @pl.when(vi == nv - 1)

@@ -16,19 +16,20 @@ anything crosses the inter-pod links (sharding/collectives.py).
 
 from __future__ import annotations
 
-import jax
 from jax.sharding import Mesh
+
+from repro.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1) -> Mesh:
     """A mesh over however many (CPU) devices the test process has."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_name(mesh: Mesh) -> str:

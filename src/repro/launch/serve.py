@@ -36,6 +36,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.models.zoo import (
         ShapeSpec,
@@ -47,6 +48,7 @@ def main() -> int:
         make_serve_step,
     )
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params, _ = build_params(cfg, args.seed)
     t_max = args.prompt_len + args.gen

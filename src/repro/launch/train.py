@@ -36,11 +36,13 @@ def main() -> int:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.data import DataConfig
     from repro.optim import AdamW, cosine_schedule
     from repro.runtime import TrainDriver
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = None
     if args.mesh == "host":

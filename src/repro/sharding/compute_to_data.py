@@ -18,8 +18,9 @@ index-sized messages — the Chaser's FORWARD, as a collective.
   *table shard* entries it needs (all-gather in the worst case / one
   GET per hop in the faithful core version).
 
-The per-shard local resolution loop is the Pallas ``chase`` kernel's job
-on TPU (kernels/chase); here the reference uses masked takes.
+The per-shard local resolution uses masked takes on every backend: the
+Pallas ``chase`` kernel (kernels/chase) is refused by the v5e compiler
+("Only 2D gather is supported") and runs only in interpret mode.
 """
 
 from __future__ import annotations

@@ -70,9 +70,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from repro.compat import make_mesh, shard_map
 from repro.sharding.collectives import compressed_psum_with_feedback
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 rng = np.random.default_rng(1)
 g = jnp.asarray(rng.normal(0, 1, (8, 128)), jnp.float32)  # one row per rank
 err = jnp.zeros((8, 128), jnp.float32)

@@ -20,7 +20,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 results = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.compat import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 
 # ---- 1. compute-to-data embedding == plain lookup
 from repro.models.embedding import embed_c2d, embed_plain
@@ -103,7 +104,7 @@ from repro.sharding.partition import param_shardings
 with tempfile.TemporaryDirectory() as td:
     save_state(td, {"params": params}, step=3)
     like = jax.eval_shape(lambda: {"params": params})
-    small_mesh = jax.make_mesh((4, 2), ("data", "model"))  # "lost" devices
+    small_mesh = make_mesh((4, 2), ("data", "model"))  # "lost" devices
     new_sh = {"params": param_shardings(params, axes, small_mesh)}
     restored, step = restore_state(td, like, shardings=new_sh)
     deltas = [float(jnp.max(jnp.abs(restored["params"][k].astype(jnp.float32)
