@@ -14,6 +14,7 @@ compiled for the PE's own device.
 from __future__ import annotations
 
 import hashlib
+import re
 import time
 
 import jax
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import SingleDeviceSharding
 
+from .. import spans
 from ..bitcode import FatBitcode
 from ..cache import CachedExecutable, TargetCodeCache
 from ..frame import Frame, FrameKind, ProtocolError
@@ -29,6 +31,14 @@ from .exec import A_NOP, region_arg_pos
 
 class ISAMismatch(RuntimeError):
     """Binary ifunc landed on a PE whose triple it was not compiled for."""
+
+
+def named(fn, ifunc: str):
+    """``fn`` renamed ``<its name>_<ifunc>`` (sanitised), so its executable
+    reads ``jit_mapped_gatherer`` in HLO and in the device trace, not
+    ``jit_mapped`` whatever the ifunc."""
+    fn.__name__ = f"{fn.__name__}_{re.sub(r'[^0-9A-Za-z_]', '_', ifunc)}"
+    return fn
 
 
 class CodeCacheLayer:
@@ -111,7 +121,12 @@ class CodeCacheLayer:
         self._gate(frame.name, frame.digest.hex(), frame.deps, exported, admitted_ttl)
         t0 = time.perf_counter()
         avals = [self._on_device(a.shape, a.dtype) for a in exported.in_avals]
-        compiled = jax.jit(exported.call).lower(*avals).compile()
+
+        def call(*args):
+            return exported.call(*args)
+
+        with spans.span("pe/compile", bucket=1) if spans.enabled else spans.NULL:
+            compiled = jax.jit(named(call, frame.name)).lower(*avals).compile()
         jit_ms = (time.perf_counter() - t0) * 1e3
         abi = "pure"
         for d in frame.deps:
@@ -142,6 +157,10 @@ class CodeCacheLayer:
         carrying new code under a known name (republished ifunc) installs
         and supersedes, it never silently runs the stale executable.
         """
+        with spans.span("pe/resolve") if spans.enabled else spans.NULL:
+            return self._resolve_exe(buf, hdr)
+
+    def _resolve_exe(self, buf: bytes, hdr) -> tuple[CachedExecutable, Frame]:
         from ..frame import unpack
 
         has_code = len(buf) >= hdr.full_total and hdr.code_len > 0
@@ -234,7 +253,8 @@ class CodeCacheLayer:
         carry with a masked ``lax.scan`` (exact sequential semantics, one
         dispatch, one region write).
         """
-        hit = self.cache.lookup_batched(exe.digest, bucket)
+        with spans.span("pe/resolve") if spans.enabled else spans.NULL:
+            hit = self.cache.lookup_batched(exe.digest, bucket)
         if hit is not None:
             return hit
         exported = exe.extras["exported"]
@@ -268,16 +288,14 @@ class CodeCacheLayer:
                 return (carry, ys) if abi == "propagate" else carry
 
             extra_avals = [a for i, a in enumerate(dep_avals) if i != rpos]
-            compiled = (
-                jax.jit(folded)
-                .lower(block_aval, valid_aval, dep_avals[rpos], *extra_avals)
-                .compile()
-            )
+            fn, avals = folded, (block_aval, valid_aval, dep_avals[rpos], *extra_avals)
         else:
             def mapped(pays, *deps):
                 return lax.map(lambda p: call(p, *deps), pays)
 
-            compiled = jax.jit(mapped).lower(block_aval, *dep_avals).compile()
+            fn, avals = mapped, (block_aval, *dep_avals)
+        with spans.span("pe/compile", bucket=bucket) if spans.enabled else spans.NULL:
+            compiled = jax.jit(named(fn, exe.name)).lower(*avals).compile()
         self.stats.jit_ms_total += (time.perf_counter() - t0) * 1e3
         self.cache.install_batched(exe.digest, bucket, compiled)
         return compiled

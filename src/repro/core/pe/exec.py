@@ -58,6 +58,7 @@ from typing import TYPE_CHECKING, Any
 
 import numpy as np
 
+from .. import spans
 from ..cache import CachedExecutable
 from ..frame import ProtocolError
 from .. import verify as _verify_codes
@@ -137,11 +138,12 @@ class ExecLayer:
 
     @staticmethod
     def decode_payload(exe: CachedExecutable, payload: bytes) -> np.ndarray:
-        aval = exe.in_avals[0]
-        if dep_named(exe, "ragged") is not None:
-            payload = ExecLayer._pad_ragged(aval, payload)
-        arr = np.frombuffer(payload, dtype=aval.dtype)
-        return arr.reshape(aval.shape)
+        with spans.span("pe/decode") if spans.enabled else spans.NULL:
+            aval = exe.in_avals[0]
+            if dep_named(exe, "ragged") is not None:
+                payload = ExecLayer._pad_ragged(aval, payload)
+            arr = np.frombuffer(payload, dtype=aval.dtype)
+            return arr.reshape(aval.shape)
 
     @staticmethod
     def decode_payload_block(
@@ -154,14 +156,17 @@ class ExecLayer:
         padding can never hang where zero-padding might; padded outputs are
         simply discarded.
         """
-        aval = exe.in_avals[0]
-        if dep_named(exe, "ragged") is not None:
-            pays = [ExecLayer._pad_ragged(aval, p) for p in pays]
-        arr = np.frombuffer(b"".join(pays), dtype=aval.dtype)
-        arr = arr.reshape((len(pays), *aval.shape))
-        if bucket > len(pays):
-            arr = np.concatenate([arr, np.repeat(arr[-1:], bucket - len(pays), axis=0)])
-        return arr
+        with spans.span("pe/decode") if spans.enabled else spans.NULL:
+            aval = exe.in_avals[0]
+            if dep_named(exe, "ragged") is not None:
+                pays = [ExecLayer._pad_ragged(aval, p) for p in pays]
+            arr = np.frombuffer(b"".join(pays), dtype=aval.dtype)
+            arr = arr.reshape((len(pays), *aval.shape))
+            if bucket > len(pays):
+                arr = np.concatenate(
+                    [arr, np.repeat(arr[-1:], bucket - len(pays), axis=0)]
+                )
+            return arr
 
     def _dep_args(self, exe: CachedExecutable) -> list[Any]:
         args: list[Any] = []
@@ -174,6 +179,22 @@ class ExecLayer:
         return args
 
     # --- invoke -------------------------------------------------------------
+    def _dispatch(self, fn, *args):
+        """Call a compiled executable; its host (numpy) arguments cross to
+        the device with the call."""
+        nbytes = sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+        self.stats.h2d_bytes += nbytes
+        with spans.span("pe/dispatch", bytes=nbytes) if spans.enabled else spans.NULL:
+            return fn(*args)
+
+    def _host(self, out) -> np.ndarray:
+        """A dispatch's output on the host: waits for the device, then
+        copies."""
+        nbytes = out.nbytes
+        self.stats.d2h_bytes += nbytes
+        with spans.span("pe/sync", bytes=nbytes) if spans.enabled else spans.NULL:
+            return np.asarray(out)
+
     def invoke(self, exe: CachedExecutable, payload: bytes) -> None:
         ver = self.verifier
         if ver is not None and ver.config.enabled:
@@ -182,24 +203,24 @@ class ExecLayer:
             ver.charge_invoke(exe, [len(payload)])
         self.stats.invokes += 1
         self.stats.invoked_payloads += 1
-        pay = self.decode_payload(exe, payload)
-        args = self._dep_args(exe)
-        out = exe.fn(pay, *args)
-        abi = exe.extras.get("abi", "pure")
-        if abi == "update":
-            region = dep_named(exe, "region")
-            assert region is not None, "update ABI requires a region dep"
-            self.rt.write_region(region, np.asarray(out))
-        elif abi == "propagate":
-            region = dep_named(exe, "region")
-            assert region is not None, "propagate ABI requires a region dep"
-            new_region, actions = out
-            self.rt.write_region(region, np.asarray(new_region))
-            self.apply_actions(exe, np.asarray(actions))
-        elif abi == "xrdma":
-            self.apply_actions(exe, np.asarray(out))
-        else:  # pure
-            self.rt.completed.append(np.asarray(out))
+        with spans.span("pe/exec", n=1, bucket=1) if spans.enabled else spans.NULL:
+            pay = self.decode_payload(exe, payload)
+            out = self._dispatch(exe.fn, pay, *self._dep_args(exe))
+            abi = exe.extras.get("abi", "pure")
+            if abi == "update":
+                region = dep_named(exe, "region")
+                assert region is not None, "update ABI requires a region dep"
+                self.rt.write_region(region, self._host(out))
+            elif abi == "propagate":
+                region = dep_named(exe, "region")
+                assert region is not None, "propagate ABI requires a region dep"
+                new_region, actions = out
+                self.rt.write_region(region, self._host(new_region))
+                self.apply_actions(exe, self._host(actions))
+            elif abi == "xrdma":
+                self.apply_actions(exe, self._host(out))
+            else:  # pure
+                self.rt.completed.append(self._host(out))
 
     def invoke_batch(self, exe: CachedExecutable, pays: list[bytes]) -> None:
         """Retire N same-ifunc payloads in one XLA dispatch."""
@@ -211,47 +232,47 @@ class ExecLayer:
             ver.charge_invoke(exe, [len(p) for p in pays])
         n = len(pays)
         bucket = self.codecache.bucket(n)
-        block = self.decode_payload_block(exe, pays, bucket)
-        fn = self.codecache.batched_executable(exe, bucket)
-        args = self._dep_args(exe)
-        abi = exe.extras.get("abi", "pure")
-        self.stats.invokes += 1
-        self.stats.batched_invokes += 1
-        self.stats.invoked_payloads += n
-        if abi in ("update", "propagate"):
-            region = dep_named(exe, "region")
-            assert region is not None, f"{abi} ABI requires a region dep"
-            valid = np.arange(bucket) < n
-            rpos = region_arg_pos(exe)
-            extra = [a for i, a in enumerate(args) if i != rpos]
-            out = fn(block, valid, args[rpos], *extra)
-            if abi == "propagate":
-                out, acts = out
-                self.rt.write_region(region, np.asarray(out))
-                # padded rows were masked to NOPs inside the scan; applying
-                # the real rows in payload order preserves the sequential
-                # semantics (the row that completes a fold emits the action)
-                for per_payload in np.asarray(acts)[:n]:
-                    self.apply_actions(exe, per_payload)
-            else:
-                self.rt.write_region(region, np.asarray(out))
-        elif abi == "xrdma":
-            actions = np.asarray(fn(block, *args))[:n]
-            for per_payload in actions:
-                self.apply_actions(exe, per_payload)
-        else:  # pure
-            outs = np.asarray(fn(block, *args))[:n]
-            self.rt.completed.extend(outs)
+        with spans.span("pe/exec", n=n, bucket=bucket) if spans.enabled else spans.NULL:
+            block = self.decode_payload_block(exe, pays, bucket)
+            fn = self.codecache.batched_executable(exe, bucket)
+            args = self._dep_args(exe)
+            abi = exe.extras.get("abi", "pure")
+            self.stats.invokes += 1
+            self.stats.batched_invokes += 1
+            self.stats.invoked_payloads += n
+            if abi in ("update", "propagate"):
+                region = dep_named(exe, "region")
+                assert region is not None, f"{abi} ABI requires a region dep"
+                valid = np.arange(bucket) < n
+                rpos = region_arg_pos(exe)
+                extra = [a for i, a in enumerate(args) if i != rpos]
+                out = self._dispatch(fn, block, valid, args[rpos], *extra)
+                if abi == "propagate":
+                    out, acts = out
+                    self.rt.write_region(region, self._host(out))
+                    # padded rows were masked to NOPs inside the scan;
+                    # applying the real rows in payload order preserves the
+                    # sequential semantics (the row that completes a fold
+                    # emits the action)
+                    self.apply_actions(exe, self._host(acts)[:n])
+                else:
+                    self.rt.write_region(region, self._host(out))
+            elif abi == "xrdma":
+                self.apply_actions(exe, self._host(self._dispatch(fn, block, *args))[:n])
+            else:  # pure
+                outs = self._host(self._dispatch(fn, block, *args))[:n]
+                self.rt.completed.extend(outs)
 
     # --- action application ---------------------------------------------------
     def apply_actions(self, exe: CachedExecutable, out: np.ndarray) -> None:
         """Apply what an xrdma entry returned: one action vector, or an
-        (R, W) matrix of action rows applied in order (see module docstring)."""
-        if out.ndim == 2:
-            for row in out:
+        (R, W) matrix of action rows applied in order (see module
+        docstring); a batched dispatch's (N, ...) stack of either is applied
+        payload by payload, in order."""
+        rows = out.reshape(-1, out.shape[-1])
+        with spans.span("pe/actions", rows=len(rows)) if spans.enabled else spans.NULL:
+            for row in rows:
                 self.apply_action(exe, row)
-        else:
-            self.apply_action(exe, out)
 
     def apply_action(self, exe: CachedExecutable, action: np.ndarray) -> None:
         """The fixed X-RDMA action protocol (see module docstring)."""

@@ -26,6 +26,7 @@ from typing import Callable, Sequence
 import jax
 import numpy as np
 
+from .. import spans
 from ..bitcode import device_of, platform_of
 from ..cache import CachedExecutable, SenderCache, TargetCodeCache
 from ..dataplane import DataPlaneConfig
@@ -49,6 +50,8 @@ class PEStats:
     invokes: int = 0  # XLA dispatches (a batched dispatch counts once)
     batched_invokes: int = 0  # dispatches that retired >1 payload
     invoked_payloads: int = 0  # payloads retired across all dispatches
+    h2d_bytes: int = 0  # regions put on the device + host arguments of dispatches
+    d2h_bytes: int = 0  # dispatch outputs copied to the host
     forwards: int = 0
     returns: int = 0
     spawns: int = 0
@@ -343,13 +346,17 @@ class PE:
         hit = self._region_dev.get(name)
         if hit is not None and hit[0] == ver:
             return hit[1]
-        dev = jax.device_put(self.endpoint.regions[name], self.device)
+        host = self.endpoint.regions[name]
+        self.stats.h2d_bytes += host.nbytes
+        with spans.span("pe/h2d", bytes=host.nbytes) if spans.enabled else spans.NULL:
+            dev = jax.device_put(host, self.device)
         self._region_dev[name] = (ver, dev)
         return dev
 
     def write_region(self, name: str, value: np.ndarray) -> None:
-        np.copyto(self.endpoint.regions[name], value)
-        self.endpoint.touch_region(name)
+        with spans.span("pe/write_region", bytes=value.nbytes) if spans.enabled else spans.NULL:
+            np.copyto(self.endpoint.regions[name], value)
+            self.endpoint.touch_region(name)
 
     def register_cap(self, name: str, arr: np.ndarray) -> None:
         self.caps[name] = np.asarray(arr)

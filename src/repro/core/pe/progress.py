@@ -37,6 +37,7 @@ from __future__ import annotations
 from collections import deque
 from typing import TYPE_CHECKING
 
+from .. import spans
 from ..cache import CachedExecutable
 from ..frame import (
     CorruptFrame,
@@ -165,6 +166,13 @@ class ProgressEngine:
         lane, and every sequenced frame's piggybacked ack retires the wire
         layer's retransmit state.  Returns buffers drained (held and
         dropped ones included: a duplicate arriving IS link progress)."""
+        with spans.span("pe/ingest") if spans.enabled else spans.NULL as sp:
+            n = self._drain_inbox()
+            if sp is not None:
+                sp.set(n=n)
+        return n
+
+    def _drain_inbox(self) -> int:
         rel = self.wire.reliability
         n = 0
         for buf in self.rt.endpoint.drain():
@@ -327,15 +335,16 @@ class ProgressEngine:
         rel = self.wire.reliability
         if rel.enabled:
             self.tick += 1
-        if self.wire.batching:
-            processed = self._poll_batched(budget)
-        else:
-            processed = self._poll_single(budget)
-        processed += self.wire.pump()
-        if rel.enabled:
-            processed += self._reliability_tick()
-            processed += self._gate_progress
-            self._gate_progress = 0
+        with spans.span("pe/poll", pe=self.rt.name) if spans.follow() else spans.NULL:
+            if self.wire.batching:
+                processed = self._poll_batched(budget)
+            else:
+                processed = self._poll_single(budget)
+            processed += self.wire.pump()
+            if rel.enabled:
+                processed += self._reliability_tick()
+                processed += self._gate_progress
+                self._gate_progress = 0
         return processed
 
     def _reliability_tick(self) -> int:

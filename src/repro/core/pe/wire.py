@@ -37,6 +37,7 @@ from typing import TYPE_CHECKING, Callable
 
 import numpy as np
 
+from .. import spans
 from ..frame import Frame, FrameFlags, FrameKind, coalesce, pack_rndv, rndv_region
 from ..reliability import ReliabilityConfig
 from ..transport import EndpointDead, Fabric, RegionWrite
@@ -448,6 +449,13 @@ class WireLayer:
         delivered, then the first error is re-raised.  Returns the number
         of wire operations issued.
         """
+        with spans.span("pe/flush") if spans.follow() else spans.NULL as sp:
+            puts = self._flush()
+            if sp is not None:
+                sp.set(puts=puts)
+        return puts
+
+    def _flush(self) -> int:
         puts = self.pump()
         queued, self._sendq = self._sendq, {}
         regionq, self._regionq = self._regionq, {}

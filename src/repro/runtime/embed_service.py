@@ -33,6 +33,7 @@ from repro.core import (
     GatherFuture,
     PropagationConfig,
 )
+from repro.core import spans
 from repro.core.transport import WireReportMixin
 from repro.core.xrdma import (
     make_filter,
@@ -71,7 +72,11 @@ class GatherRequest:
     tenant: str | None = None  # whose credit budget the frames charge
     express: bool = False  # control-lane drain priority at the servers
     slot_quota: int = 0  # max CQ slots this tenant may hold (0 = uncapped)
-    t_admit: float = 0.0  # when the request last entered the fabric
+
+
+def _span_args(reqs: list[GatherRequest]) -> dict:
+    """A service span's arguments: how many requests, and which when one."""
+    return {"n": len(reqs), "rid": reqs[0].rid} if len(reqs) == 1 else {"n": len(reqs)}
 
 
 @dataclass
@@ -275,7 +280,16 @@ class EmbedShardService:
         return None
 
     def _admit(self) -> int:
-        admitted = 0
+        with spans.span("svc/admit") if spans.enabled else spans.NULL as sp:
+            admitted = self._admit_queued()
+            if sp is not None:
+                sp.set(**_span_args(admitted))
+        return len(admitted)
+
+    def _admit_queued(self) -> list[GatherRequest]:
+        """Admit queued requests into free CQ slots; returns those that
+        left the queue (completed degraded ones included)."""
+        admitted: list[GatherRequest] = []
         dead = self._dead_peers() if self.cluster.client.reliability.enabled else set()
         held: list[GatherRequest] = []
         while self.queue:
@@ -291,7 +305,7 @@ class EmbedShardService:
                 req.done = True
                 req.t_done = time.perf_counter()
                 self.finished.append(req)
-                admitted += 1
+                admitted.append(req)
                 continue
             fut = self.cluster.client.submit(
                 entry,
@@ -319,9 +333,8 @@ class EmbedShardService:
                 continue
             fut.attempts = req.resubmits
             req.future = fut
-            req.t_admit = time.perf_counter()
             self.active[fut.slot] = req
-            admitted += 1
+            admitted.append(req)
         for req in reversed(held):
             self.queue.appendleft(req)
         return admitted
@@ -386,31 +399,35 @@ class EmbedShardService:
         return actions
 
     def _retire(self) -> int:
-        retired = 0
-        for slot, req in list(self.active.items()):
-            assert req.future is not None
-            if req.future.done():
-                req.rows = req.future.result()[: len(req.keys)]
-                req.done = True
-                req.t_done = time.perf_counter()
-                self.finished.append(req)
-                del self.active[slot]
-                retired += 1
-        return retired
+        with spans.span("svc/retire") if spans.enabled else spans.NULL as sp:
+            retired = []
+            for slot, req in list(self.active.items()):
+                assert req.future is not None
+                if req.future.done():
+                    req.rows = req.future.result()[: len(req.keys)]
+                    req.done = True
+                    req.t_done = time.perf_counter()
+                    self.finished.append(req)
+                    del self.active[slot]
+                    retired.append(req)
+            if sp is not None:
+                sp.set(**_span_args(retired))
+        return len(retired)
 
     def tick(self) -> int:
         """One scheduler round: admit -> flush -> poll every PE -> recover
         -> retire.  Returns a progress count (admissions + polled messages
         + recovery actions + retires)."""
-        self.ticks += 1
-        self.cq.advance()
-        progress = self._admit()
-        if self.batching:
-            self.cluster.client.flush()
-        for pe in self.cluster.alive_pes():
-            progress += pe.poll()
-        progress += self._recover()
-        progress += self._retire()
+        with spans.span("svc/tick") if spans.follow() else spans.NULL:
+            self.ticks += 1
+            self.cq.advance()
+            progress = self._admit()
+            if self.batching:
+                self.cluster.client.flush()
+            for pe in self.cluster.alive_pes():
+                progress += pe.poll()
+            progress += self._recover()
+            progress += self._retire()
         return progress
 
     def _outstanding_detail(self) -> str:
