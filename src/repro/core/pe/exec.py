@@ -99,6 +99,34 @@ def region_arg_pos(exe: CachedExecutable) -> int:
     raise AssertionError("update ABI requires a region dep")
 
 
+class Pending:
+    """A dispatch whose outputs are still on their way to the host: what
+    :meth:`ExecLayer.complete` needs to finish it."""
+
+    __slots__ = ("exe", "fn", "n", "batched", "host_args", "abi", "region", "reads",
+                 "vers", "out")
+
+    def __init__(self, exe: CachedExecutable, fn, n: int, batched: bool,
+                 host_args: list[np.ndarray]) -> None:
+        self.exe = exe
+        self.fn = fn
+        self.n = n  # real payloads (a batched block is padded to its bucket)
+        self.batched = batched
+        self.host_args = host_args  # the payload or block (and valid mask)
+        self.abi = exe.extras.get("abi", "pure")
+        self.region = None  # the region an update/propagate result is stored to
+        if self.abi in ("update", "propagate"):
+            self.region = dep_named(exe, "region")
+            assert self.region is not None, f"{self.abi} ABI requires a region dep"
+        self.reads = [v for t, _, v in (d.partition(":") for d in exe.deps) if t == "region"]
+        self.vers: list[int] = []  # the versions of ``reads`` the dispatch saw
+        self.out = None
+
+    def rows(self, out: np.ndarray) -> np.ndarray:
+        """The real payloads' outputs of a batched dispatch."""
+        return out[: self.n] if self.batched else out
+
+
 class ExecLayer:
     """Invoke + action application for one PE.
 
@@ -195,7 +223,24 @@ class ExecLayer:
         with spans.span("pe/sync", bytes=nbytes) if spans.enabled else spans.NULL:
             return np.asarray(out)
 
-    def invoke(self, exe: CachedExecutable, payload: bytes) -> None:
+    def _versions(self, regions: list[str]) -> list[int]:
+        return [self.rt.endpoint.region_ver.get(r, 0) for r in regions]
+
+    def _launch(self, p: "Pending") -> None:
+        """Dispatch ``p`` on the regions as they stand now, and start
+        copying its outputs to the host without waiting for them."""
+        args = self._dep_args(p.exe)
+        p.vers = self._versions(p.reads)
+        if p.region is not None and p.batched:  # the masked scan takes the region first
+            rpos = region_arg_pos(p.exe)
+            args = [args[rpos], *(a for i, a in enumerate(args) if i != rpos)]
+        out = self._dispatch(p.fn, *p.host_args, *args)
+        for leaf in out if isinstance(out, (tuple, list)) else (out,):
+            leaf.copy_to_host_async()
+        p.out = out
+
+    def _begin_one(self, exe: CachedExecutable, payload: bytes) -> "Pending":
+        """Decode and dispatch one payload; :meth:`complete` finishes it."""
         ver = self.verifier
         if ver is not None and ver.config.enabled:
             # retire-time quota charge, before the dispatch: code over its
@@ -204,29 +249,15 @@ class ExecLayer:
         self.stats.invokes += 1
         self.stats.invoked_payloads += 1
         with spans.span("pe/exec", n=1, bucket=1) if spans.enabled else spans.NULL:
-            pay = self.decode_payload(exe, payload)
-            out = self._dispatch(exe.fn, pay, *self._dep_args(exe))
-            abi = exe.extras.get("abi", "pure")
-            if abi == "update":
-                region = dep_named(exe, "region")
-                assert region is not None, "update ABI requires a region dep"
-                self.rt.write_region(region, self._host(out))
-            elif abi == "propagate":
-                region = dep_named(exe, "region")
-                assert region is not None, "propagate ABI requires a region dep"
-                new_region, actions = out
-                self.rt.write_region(region, self._host(new_region))
-                self.apply_actions(exe, self._host(actions))
-            elif abi == "xrdma":
-                self.apply_actions(exe, self._host(out))
-            else:  # pure
-                self.rt.completed.append(self._host(out))
+            p = Pending(exe, exe.fn, 1, False, [self.decode_payload(exe, payload)])
+            self._launch(p)
+        return p
 
-    def invoke_batch(self, exe: CachedExecutable, pays: list[bytes]) -> None:
-        """Retire N same-ifunc payloads in one XLA dispatch."""
+    def invoke_batch(self, exe: CachedExecutable, pays: list[bytes]) -> "Pending":
+        """Decode N same-ifunc payloads into one block and dispatch it: one
+        XLA dispatch retires them all once :meth:`complete` has run."""
         if len(pays) == 1:  # the per-message executable is already compiled
-            self.invoke(exe, pays[0])
-            return
+            return self._begin_one(exe, pays[0])
         ver = self.verifier
         if ver is not None and ver.config.enabled:
             ver.charge_invoke(exe, [len(p) for p in pays])
@@ -235,33 +266,51 @@ class ExecLayer:
         with spans.span("pe/exec", n=n, bucket=bucket) if spans.enabled else spans.NULL:
             block = self.decode_payload_block(exe, pays, bucket)
             fn = self.codecache.batched_executable(exe, bucket)
-            args = self._dep_args(exe)
-            abi = exe.extras.get("abi", "pure")
             self.stats.invokes += 1
             self.stats.batched_invokes += 1
             self.stats.invoked_payloads += n
-            if abi in ("update", "propagate"):
-                region = dep_named(exe, "region")
-                assert region is not None, f"{abi} ABI requires a region dep"
-                valid = np.arange(bucket) < n
-                rpos = region_arg_pos(exe)
-                extra = [a for i, a in enumerate(args) if i != rpos]
-                out = self._dispatch(fn, block, valid, args[rpos], *extra)
-                if abi == "propagate":
-                    out, acts = out
-                    self.rt.write_region(region, self._host(out))
-                    # padded rows were masked to NOPs inside the scan;
-                    # applying the real rows in payload order preserves the
-                    # sequential semantics (the row that completes a fold
-                    # emits the action)
-                    self.apply_actions(exe, self._host(acts)[:n])
-                else:
-                    self.rt.write_region(region, self._host(out))
-            elif abi == "xrdma":
-                self.apply_actions(exe, self._host(self._dispatch(fn, block, *args))[:n])
-            else:  # pure
-                outs = self._host(self._dispatch(fn, block, *args))[:n]
-                self.rt.completed.extend(outs)
+            host_args = [block]
+            if exe.extras.get("abi", "pure") in ("update", "propagate"):
+                host_args.append(np.arange(bucket) < n)  # padded rows fold nothing
+            p = Pending(exe, fn, n, True, host_args)
+            self._launch(p)
+        return p
+
+    def complete(self, p: "Pending") -> None:
+        """Wait for a dispatch's outputs and apply them: store the region,
+        apply the action rows in payload order, or collect the results."""
+        with spans.span("pe/exec", n=p.n) if spans.enabled else spans.NULL:
+            self._complete(p)
+
+    def _complete(self, p: "Pending") -> None:
+        exe, abi = p.exe, p.abi
+        if self._versions(p.reads) != p.vers:
+            # a region changed after the dispatch read it (an earlier
+            # dispatch of this poll stored to it, or a one-sided RETURN
+            # landed): its result is stale, and storing it would overwrite
+            # those bytes, so dispatch again on the regions as they stand
+            self.stats.redispatches += 1
+            self._launch(p)
+        if p.region is not None:
+            if abi == "propagate":
+                new_region, actions = p.out
+                self.rt.write_region(p.region, self._host(new_region))
+                # padded rows were masked to NOPs inside the scan; applying
+                # the real rows in payload order preserves the sequential
+                # semantics (the row that completes a fold emits the action)
+                self.apply_actions(exe, p.rows(self._host(actions)))
+            else:
+                self.rt.write_region(p.region, self._host(p.out))
+        elif abi == "xrdma":
+            self.apply_actions(exe, p.rows(self._host(p.out)))
+        elif p.batched:  # pure
+            self.rt.completed.extend(self._host(p.out)[: p.n])
+        else:
+            self.rt.completed.append(self._host(p.out))
+
+    def invoke(self, exe: CachedExecutable, payload: bytes) -> None:
+        """Retire one payload: dispatch it and wait for it."""
+        self.complete(self._begin_one(exe, payload))
 
     # --- action application ---------------------------------------------------
     def apply_actions(self, exe: CachedExecutable, out: np.ndarray) -> None:

@@ -52,6 +52,8 @@ class PEStats:
     invoked_payloads: int = 0  # payloads retired across all dispatches
     h2d_bytes: int = 0  # regions put on the device + host arguments of dispatches
     d2h_bytes: int = 0  # dispatch outputs copied to the host
+    overlapped_waits: int = 0  # dispatches completed while another PE's were in flight
+    redispatches: int = 0  # dispatches made again: a region they read changed in flight
     forwards: int = 0
     returns: int = 0
     spawns: int = 0
@@ -603,6 +605,22 @@ class PE:
         """Drive the progress engine one step (see
         :meth:`repro.core.pe.progress.ProgressEngine.poll`)."""
         return self.progress.poll(max_msgs)
+
+    def poll_begin(self, max_msgs: int | None = None) -> int:
+        """The first half of :meth:`poll`: take arrivals and dispatch them
+        (see :meth:`repro.core.pe.progress.ProgressEngine.poll_begin`)."""
+        return self.progress.poll_begin(max_msgs)
+
+    def poll_complete(self, others: int = 0) -> int:
+        """The second half of :meth:`poll`: wait for this PE's dispatches,
+        apply them and flush; ``others`` counts other PEs' dispatches still
+        in flight (see :meth:`repro.core.pe.progress.ProgressEngine.poll_complete`)."""
+        return self.progress.poll_complete(others)
+
+    @property
+    def in_flight(self) -> int:
+        """Dispatches begun by :meth:`poll_begin` and not yet completed."""
+        return self.progress.in_flight
 
     def flush(self) -> int:
         """Emit every queued frame and one-sided write burst (see

@@ -55,6 +55,7 @@ from ..liveness import HeartbeatMonitor
 from ..propagate import tree_children
 from ..transport import EndpointDead
 from .codecache import ISAMismatch
+from .exec import Pending
 from .wire import is_control
 
 if TYPE_CHECKING:  # pragma: no cover - annotations only
@@ -150,6 +151,9 @@ class ProgressEngine:
         # publish dedup keys waiting to retire: (src, seq, key) retired
         # once the ack for seq has actually been stamped toward src
         self._pub_log: deque[tuple[str, int, tuple]] = deque()
+        # what poll_begin left for poll_complete: Pending dispatches and
+        # errors, in order (None: no batched poll is open)
+        self._begun: list | None = None
 
     # --- lane bookkeeping --------------------------------------------------
     def _ingest(self) -> int:
@@ -329,17 +333,49 @@ class ProgressEngine:
         ``(B, ...)`` block and retired by a single batched XLA dispatch,
         and everything the dispatches emitted is flushed as coalesced
         per-destination PUTs.  Returns a progress count: frames processed
-        plus credit-stalled sends pumped.
+        plus credit-stalled sends pumped.  One poll is :meth:`poll_begin`
+        followed by :meth:`poll_complete`.
         """
+        return self.poll_begin(max_msgs) + self.poll_complete()
+
+    @property
+    def in_flight(self) -> int:
+        """Dispatches :meth:`poll_begin` left for :meth:`poll_complete`."""
+        return sum(isinstance(p, Pending) for p in self._begun or ())
+
+    def poll_begin(self, max_msgs: int | None = None) -> int:
+        """Take this poll's arrivals and dispatch them, without waiting for
+        the device: a caller that drives several PEs begins each before it
+        completes any, so their round trips to the device overlap.  The
+        per-message mode (batching off) runs its whole poll here and leaves
+        nothing in flight.  Returns the frames taken."""
         budget = max_msgs if max_msgs is not None else self.budget
-        rel = self.wire.reliability
-        if rel.enabled:
+        if self.wire.reliability.enabled:
             self.tick += 1
-        with spans.span("pe/poll", pe=self.rt.name) if spans.follow() else spans.NULL:
+        with spans.span("pe/poll", pe=self.rt.name, phase="begin") if spans.follow() else spans.NULL:
             if self.wire.batching:
-                processed = self._poll_batched(budget)
-            else:
-                processed = self._poll_single(budget)
+                return self._begin_batched(budget)
+            return self._poll_single(budget)
+
+    def poll_complete(self, others: int = 0) -> int:
+        """Finish what :meth:`poll_begin` dispatched, in its order: wait for
+        each dispatch's outputs and apply them, flush what they emitted,
+        pump credit-stalled sends and run the reliability tick.  ``others``
+        counts the dispatches of other PEs still in flight as this starts
+        (``PEStats.overlapped_waits``).  Every healthy group completes and
+        the flush runs before the first error of the poll is raised.
+        Returns a progress count: stalled sends pumped and recovery work."""
+        rel = self.wire.reliability
+        with spans.span("pe/poll", pe=self.rt.name, phase="complete") if spans.follow() else spans.NULL:
+            processed = 0
+            if self._begun is not None:
+                if others:
+                    self.stats.overlapped_waits += self.in_flight
+                begun, self._begun = self._begun, None
+                try:
+                    self._complete_batch(begun)
+                finally:
+                    self.wire.flush()  # emitted actions travel even if a frame was bad
             processed += self.wire.pump()
             if rel.enabled:
                 processed += self._reliability_tick()
@@ -424,12 +460,12 @@ class ProgressEngine:
                 tracer.emit("poll", src=self.rt.name, tick=self.tick, p=used)
         return n
 
-    def _poll_batched(self, budget: int | None) -> int:
+    def _begin_batched(self, budget: int | None) -> int:
         """Batched mode: take up to ``budget`` payloads (control lane
         first, big coalesced frames consumed partially), handle control/AM
-        inline, group data payloads by code digest, and retire each group
-        in ONE batched XLA dispatch; then flush the coalesced output burst
-        even if a frame was bad."""
+        inline, group data payloads by code digest, and dispatch each group
+        in ONE batched XLA dispatch; :meth:`poll_complete` retires them and
+        flushes the coalesced output burst even if a frame was bad."""
         self._ingest()
         taken: list[tuple[bytes, int, int | None, str]] = []  # (buf, start, stop, src)
         used = 0
@@ -465,10 +501,7 @@ class ProgressEngine:
         if taken and tracer is not None:
             tracer.emit("poll", src=self.rt.name, tick=self.tick, p=used)
         if taken:
-            try:
-                self._execute_batch(taken)
-            finally:
-                self.wire.flush()  # emitted actions travel even if a frame was bad
+            self._begun = (self._begun or []) + self._begin_batch(taken)
         return len(taken)
 
     # --- frame routing -----------------------------------------------------
@@ -501,19 +534,21 @@ class ProgressEngine:
         for pay in split_payloads(frame)[start:]:
             self.execl.invoke(exe, pay)
 
-    def _execute_batch(self, bufs: list[tuple[bytes, int, int | None]]) -> None:
-        """Group frames by code digest and invoke each group once.
+    def _begin_batch(self, bufs: list[tuple[bytes, int, int | None, str]]) -> list:
+        """Group frames by code digest and dispatch each group once.
 
-        Each entry is ``(buf, start, stop)``: the payload slice the budget
-        admitted this poll (``(buf, 0, None)`` = the whole frame).  A frame
-        that fails to resolve (stale sender cache after a restart) or a
-        group that fails to invoke (corrupt payload block) must not take
-        the rest of the batch down with it: every healthy frame/group is
-        still processed, then the first error is re-raised — the same
-        blast radius as the per-message path.
+        Each entry is ``(buf, start, stop, src)``: the payload slice the
+        budget admitted this poll (``(buf, 0, None, src)`` = the whole
+        frame).  A frame that fails to resolve (stale sender cache after a
+        restart) or a group that fails to dispatch (corrupt payload block)
+        must not take the rest of the batch down with it: every healthy
+        frame/group is still processed, and the errors are raised after
+        (see :meth:`_complete_batch`) — the same blast radius as the
+        per-message path.  Returns the groups' :class:`Pending` dispatches
+        and the errors, in the order they are to be completed or raised.
         """
         groups: dict[bytes, tuple[CachedExecutable, list[bytes]]] = {}
-        errors: list[Exception] = []
+        begun: list = []
         for buf, start, stop, src in bufs:
             try:
                 hdr = peek_header(buf)
@@ -544,10 +579,24 @@ class ProgressEngine:
                 entry = groups.setdefault(hdr.digest, (exe, []))
                 entry[1].extend(split_payloads(frame)[start:stop])
             except (ProtocolError, ValueError, ISAMismatch, EndpointDead) as e:
-                errors.append(e)
+                begun.append(e)
         for exe, pays in groups.values():
             try:
-                self.execl.invoke_batch(exe, pays)
+                begun.append(self.execl.invoke_batch(exe, pays))
+            except Exception as e:  # noqa: BLE001 - process remaining groups
+                begun.append(e)
+        return begun
+
+    def _complete_batch(self, begun: list) -> None:
+        """Complete each dispatch of :meth:`_begin_batch` in order, then
+        raise the first error of the poll."""
+        errors: list[Exception] = []
+        for p in begun:
+            if isinstance(p, Exception):
+                errors.append(p)
+                continue
+            try:
+                self.execl.complete(p)
             except Exception as e:  # noqa: BLE001 - process remaining groups
                 errors.append(e)
         if errors:

@@ -158,6 +158,8 @@ class EmbedShardService:
         self._next_rid = 0
         self.batching = False
         self.ticks = 0  # scheduler rounds driven; also the CQ deadline clock
+        # dispatches in flight across PEs when each tick's first wait began
+        self.inflight_at_wait = 0
 
     # ------------------------------------------------------------------ util
     def owner(self, key: int) -> int:
@@ -417,15 +419,28 @@ class EmbedShardService:
     def tick(self) -> int:
         """One scheduler round: admit -> flush -> poll every PE -> recover
         -> retire.  Returns a progress count (admissions + polled messages
-        + recovery actions + retires)."""
-        with spans.span("svc/tick") if spans.follow() else spans.NULL:
+        + recovery actions + retires).
+
+        Each PE's poll is split in two: every PE begins (takes its arrivals
+        and dispatches them) before any completes (waits for its outputs),
+        so the PEs' round trips to the device overlap, as their nodes would
+        run side by side.  A frame a PE emits is handled in the next tick."""
+        with spans.span("svc/tick") if spans.follow() else spans.NULL as sp:
             self.ticks += 1
             self.cq.advance()
             progress = self._admit()
             if self.batching:
                 self.cluster.client.flush()
-            for pe in self.cluster.alive_pes():
-                progress += pe.poll()
+            pes = self.cluster.alive_pes()
+            for pe in pes:
+                progress += pe.poll_begin()
+            inflight = sum(pe.in_flight for pe in pes)
+            self.inflight_at_wait += inflight
+            if sp is not None:
+                sp.set(inflight=inflight)
+            for pe in pes:
+                inflight -= pe.in_flight
+                progress += pe.poll_complete(others=inflight)
             progress += self._recover()
             progress += self._retire()
         return progress

@@ -60,7 +60,8 @@ def test_forced_on_totals_carry_the_byte_counters(batching):
                  "pe/resolve", "pe/exec", "pe/decode", "pe/dispatch", "pe/sync",
                  "pe/actions", "pe/write_region", "pe/h2d") + ("pe/flush",) * batching:
         assert tot[name][0] > 0 and tot[name][1] >= 0, name
-    assert tot["pe/exec"][0] == tot["pe/dispatch"][0]
+    # a dispatch's exec work has two spans: its dispatch, and its completion
+    assert tot["pe/exec"][0] == 2 * tot["pe/dispatch"][0]
     assert sum(tot[n][2] for n in ("pe/dispatch", "pe/h2d", "pe/sync")) == (h1 - h0) + (d1 - d0)
     assert tot["pe/h2d"][2] + tot["pe/dispatch"][2] == h1 - h0
     assert tot["pe/sync"][2] == d1 - d0
@@ -107,3 +108,68 @@ def test_executables_named_after_their_ifunc():
     folded = [client.target_cache.lookup_batched(fold.digest, b) for b in (2, 4, 8)]
     assert any(m is not None and "HloModule jit_folded_gather_return" in m.as_text()
                for m in folded)
+
+
+def test_two_phase_tick_spans(monkeypatch):
+    """A tick begins every PE's poll before it completes any: each phase is
+    a ``pe/poll`` (``phase`` begin or complete) under ``svc/tick``, the
+    dispatches sit in the begin phases, the waits (``pe/sync``, with no
+    span inside) in the complete phases, and ``svc/tick`` records the
+    dispatches in flight at its first wait."""
+    class Recorded:
+        stack: list = []
+        events: list = []
+
+        def __init__(self, name, **args):
+            self.name, self.args, self.children = name, dict(args), []
+
+        def set_metadata(self, **args):
+            self.args.update(args)
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+        def __enter__(self):
+            self.parent = self.stack[-1] if self.stack else None
+            if self.parent is not None:
+                self.parent.children.append(self)
+            self.stack.append(self)
+            self.events.append(self)
+
+        def __exit__(self, *exc):
+            self.stack.pop()
+
+    svc = make_service()
+    keys = np.array([1, 2, VOCAB // 2 + 1], I32)  # entry on server 0, one FORWARD
+    svc.gather([keys], batching=True)
+    svc.cluster.set_batching(True)
+    svc.batching = True
+    svc.submit(keys)
+    monkeypatch.setattr(spans, "TraceAnnotation", Recorded)
+    spans.enable(True)
+    try:
+        svc.run()
+    finally:
+        spans.enable(None)
+    np.testing.assert_array_equal(svc.finished[-1].rows, svc.table[keys])
+    ticks = [e for e in Recorded.events if e.name == "svc/tick"]
+    assert [t.args["inflight"] for t in ticks] == [1, 2, 1]
+    polls = [e for e in Recorded.events if e.name == "pe/poll"]
+    assert {p.parent.name for p in polls} == {"svc/tick"}
+    for tick in ticks:
+        phases = [p.args["phase"] for p in tick.children if p.name == "pe/poll"]
+        n = SERVERS + 1
+        assert phases == ["begin"] * n + ["complete"] * n
+    phase_of = {}
+    for e in Recorded.events:
+        p = e.parent
+        while p is not None and p.name != "pe/poll":
+            p = p.parent
+        phase_of[id(e)] = p.args["phase"] if p is not None else None
+    sync = [e for e in Recorded.events if e.name == "pe/sync"]
+    dispatch = [e for e in Recorded.events if e.name == "pe/dispatch"]
+    assert len(sync) == len(dispatch) == 4  # entry, forward, two folds
+    assert all(s.parent.name == "pe/exec" and not s.children for s in sync)
+    assert {phase_of[id(s)] for s in sync} == {"complete"}
+    assert {phase_of[id(d)] for d in dispatch} == {"begin"}
