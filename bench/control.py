@@ -6,8 +6,9 @@ several seeds in one process (the benchmark's own runs never do this).
 For each seed: build, warm up and measure the cell as ``run.py`` does,
 then compare the same retired requests twice: as the program answered
 them, and as the control answers them (the reference one step below the
-configuration's guarantee; see ``check.py``).  One JSON line per seed.
-The control has to fail a number on every seed; the program, none.
+configuration's guarantee: the ``check`` of the cell's kind module).  One
+JSON line per seed.  The control has to fail a number on every seed; the
+program, none.
 """
 
 import gc

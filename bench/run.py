@@ -169,13 +169,13 @@ def start_chip(chips: int, peaks: dict):
 def measure(cell_spec: spec.Cell, seed: int, seconds: float, devices, peaks: dict,
             compiles: CompileCounter, traced: bool = False, trace_dir: str | None = None,
             before_window=None) -> Measured:
-    """Build, warm up, measure ``seconds`` (traced: at most ``TRACE_CAP_S``
-    under the profiler), drain, read the memory peak and free the system
-    under test.  ``before_window(cell)``, for the tests, runs between
-    warm-up and window."""
+    """Build the cell's kind on ``devices``, warm up, measure ``seconds``
+    (traced: at most ``TRACE_CAP_S`` under the profiler), drain, read the
+    memory peak and free the system under test.  ``before_window(cell)``,
+    for the tests, runs between warm-up and window."""
     spans = Spans(traced)
     t = time.perf_counter()
-    cell = build_cell(cell_spec.config, cell_spec.traffic, seed, spans)
+    cell = build_cell(cell_spec.config, cell_spec.traffic, seed, spans, devices)
     t_built = time.perf_counter()
     passes = warm(cell, compiles)
     log(f"bench: set-up {time.perf_counter() - T_PROCESS:.3f} s: start "

@@ -1,17 +1,20 @@
 """The benchmark's data: ``BENCHMARK.json`` at the checkout root, one JSON
 file per configuration (``configs/``) and per traffic mix (``traffic/``),
-the table of peaks, and one reader module per metric (``metrics/``).
-Everything is found by the name ``BENCHMARK.json`` gives it."""
+the table of peaks, one reader module per metric (``metrics/``), and one
+module per kind of service (``kinds/``).  Everything is found by the name
+``BENCHMARK.json`` or a configuration gives it."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+KINDS_DIR = BENCH_DIR / "kinds"
 
 
 def load_json(path: Path) -> dict:
@@ -76,3 +79,23 @@ def metric_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def kind_path(service: str) -> Path:
+    """``kinds/<service>.py``: the module of a configuration's ``service``."""
+    return KINDS_DIR / f"{service}.py"
+
+
+def load_kind(service: str):
+    """The kind module :func:`kind_path` names, loaded by path; raises
+    ``KeyError`` naming the kinds there when it has none of ``service``."""
+    path = kind_path(service)
+    if service.startswith("_") or not path.is_file():
+        kinds = sorted(p.stem for p in KINDS_DIR.glob("*.py") if not p.stem.startswith("_"))
+        raise KeyError(f"no kind module for service {service!r} in {KINDS_DIR}: {kinds}")
+    name = "bench_kind_" + service
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod  # check.check_cell finds ``check`` by the cell's class
+    mod_spec.loader.exec_module(mod)
+    return mod

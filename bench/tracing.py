@@ -3,12 +3,14 @@ window, and the reduction of its trace to device busy time, idle gaps by
 what the host was doing, and device time per operation name.
 
 Spans are ``jax.profiler.TraceAnnotation``s named ``bench/...``; when the
-run is not traced they cost one ``nullcontext``.  The per-PE ``poll`` and
-``flush`` the program calls from inside ``EmbedShardService.tick`` are
-wrapped on each PE instance, so the idle gaps between device operations
-are named by the poll (``bench/poll server`` or ``bench/poll client``)
-the host was in.  A kernel's device time is keyed on its operation's name
-in the trace (``embed_lookup``, the Pallas custom call), not on a span.
+run is not traced they cost one ``nullcontext``.  Each PE instance's
+``poll``, its two halves ``poll_begin`` and ``poll_complete`` (which
+``EmbedShardService.tick`` calls for every PE in turn), and ``flush`` are
+wrapped, so the idle gaps between device operations are named by the
+call the host was in and the PE's role (``bench/poll_begin server``,
+``bench/poll_complete client``, ``bench/poll server``, ...).  A kernel's
+device time is keyed on its operation's name in the trace
+(``embed_lookup``, the Pallas custom call), not on a span.
 """
 
 from __future__ import annotations
@@ -44,13 +46,14 @@ class Spans:
         return self._annotation(name) if self.on else _NULL
 
     def wrap(self, cluster) -> None:
-        """Put a span around every PE's ``poll`` and ``flush``, named by
-        the PE's role (``server`` or ``client``)."""
+        """Put a span around every PE's ``poll``, ``poll_begin``,
+        ``poll_complete`` and ``flush``, named by the method and the PE's
+        role (``server`` or ``client``)."""
         if not self.on:
             return
         for pe in cluster.pes():
             role = "client" if pe is cluster.client else "server"
-            for method in ("poll", "flush"):
+            for method in ("poll", "poll_begin", "poll_complete", "flush"):
                 inner = getattr(pe, method)
                 setattr(pe, method, self._spanned(f"bench/{method} {role}", inner))
 

@@ -58,6 +58,14 @@ def test_spans_nest_under_the_profiler(traced):
     assert sum(own for _, _, _, own in nested) == top  # the self times partition the ticks
 
 
+def test_harness_spans_name_each_half_of_a_poll(traced):
+    _, _, path = traced
+    inside, _, _ = program_spans.read_trace(path)
+    harness = {ev[0] for ev in inside if ev[0].startswith("bench/")}
+    assert {f"bench/{half} {role}" for half in ("poll_begin", "poll_complete")
+            for role in ("server", "client")} <= harness
+
+
 def test_program_totals_agree_with_the_trace(traced):
     m, totals, path = traced
     red = program_spans.reduce(path)
