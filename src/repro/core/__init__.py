@@ -94,6 +94,7 @@ from .xrdma import (
     make_filter_return,
     make_gather_return,
     make_gatherer,
+    make_kv_update,
     make_gossiper,
     make_reducer,
     make_return_result,
@@ -161,6 +162,7 @@ __all__ = [
     "make_filter_return",
     "make_gather_return",
     "make_gatherer",
+    "make_kv_update",
     "make_gossiper",
     "make_reducer",
     "make_return_result",

@@ -7,7 +7,7 @@ Layering (each module imports downward only; the facade composes them):
                staging, per-peer credit-based flow control
   codecache  — install/digest-validate arriving code, bucketed batched
                executables over the TargetCodeCache
-  exec       — invoke + masked-scan update ABI + the X-RDMA action protocol
+  exec       — invoke + update-ABI fold + the X-RDMA action protocol
   progress   — the ProgressEngine poll loop: priority lanes, per-poll
                budget, credit return
   cq         — completion queues + futures for overlapped submissions

@@ -5,7 +5,7 @@ Target side of Sec. III-C/D: extract the triple's slice from a fat-bitcode
 archive -> (ORC-)JIT -> digest cache, with the name registry deciding
 whether a truncated (digest-only) frame is acceptable and the digest
 deciding whether a name's code is *current*.  The batched renderings —
-``lax.map`` for value ABIs, the masked ``lax.scan`` fold for
+``lax.map`` for value ABIs, the fold over the real payloads for
 update/propagate ABIs — are cached per (digest, power-of-two bucket) in
 the same :class:`repro.core.cache.TargetCodeCache`.  Every executable is
 compiled for the PE's own device.
@@ -125,13 +125,16 @@ class CodeCacheLayer:
         def call(*args):
             return exported.call(*args)
 
-        with spans.span("pe/compile", bucket=1) if spans.enabled else spans.NULL:
-            compiled = jax.jit(named(call, frame.name)).lower(*avals).compile()
-        jit_ms = (time.perf_counter() - t0) * 1e3
         abi = "pure"
         for d in frame.deps:
             if d.startswith("abi:"):
                 abi = d.split(":", 1)[1]
+        # an update's region is donated: its result is written in place
+        donate = (1 + region_arg_pos(frame),) if abi in ("update", "propagate") else ()
+        with spans.span("pe/compile", bucket=1) if spans.enabled else spans.NULL:
+            compiled = jax.jit(
+                named(call, frame.name), donate_argnums=donate).lower(*avals).compile()
+        jit_ms = (time.perf_counter() - t0) * 1e3
         exe = CachedExecutable(
             name=frame.name,
             digest=frame.digest.hex(),
@@ -249,9 +252,9 @@ class CodeCacheLayer:
         Value ABIs run ``lax.map`` over the payload block — sequential
         semantics inside ONE fused XLA dispatch, which is the quantity
         being amortized (``jax.vmap`` has no batching rule for
-        ``call_exported``).  update-ABI code folds payloads into the region
-        carry with a masked ``lax.scan`` (exact sequential semantics, one
-        dispatch, one region write).
+        ``call_exported``).  update-ABI code folds the real payloads into
+        the donated region with a loop (exact sequential semantics, one
+        dispatch, the region written in place).
         """
         with spans.span("pe/resolve") if spans.enabled else spans.NULL:
             hit = self.cache.lookup_batched(exe.digest, bucket)
@@ -264,38 +267,45 @@ class CodeCacheLayer:
         block_aval = self._on_device((bucket, *pay_aval.shape), pay_aval.dtype)
         dep_avals = tuple(self._on_device(a.shape, a.dtype) for a in exe.in_avals[1:])
         t0 = time.perf_counter()
+        donate = ()
         if abi in ("update", "propagate"):
             # entry(payload, ..region.., ...) -> new_region (update) or
-            # (new_region, actions) (propagate), folded as a scan carry;
-            # padded rows are masked out so the fold is exact — a masked
-            # propagate row contributes neither to the region nor an action
-            # (its row is overwritten with NOPs).
+            # (new_region, actions) (propagate), folded over the real
+            # payloads alone — the valid mask is a prefix, so a loop over
+            # its first n rows carries the donated region and its work does
+            # not scale with the region; a padded propagate row emits NOPs.
             valid_aval = self._on_device((bucket,), jnp.bool_)
             rpos = region_arg_pos(exe)
 
             def folded(pays, valid, region, *extra):
-                def step(r, pv):
-                    p, v = pv
+                def apply(p, r):
                     dep_args = list(extra)
                     dep_args.insert(rpos, r)
-                    if abi == "propagate":
-                        nr, acts = call(p, *dep_args)
-                        nops = jnp.zeros_like(acts).at[..., 0].set(A_NOP)
-                        return jnp.where(v, nr, r), jnp.where(v, acts, nops)
-                    return jnp.where(v, call(p, *dep_args), r), None
+                    return call(p, *dep_args)
 
-                carry, ys = lax.scan(step, region, (pays, valid))
-                return (carry, ys) if abi == "propagate" else carry
+                n = jnp.sum(valid.astype(jnp.int32))
+                if abi == "update":
+                    return lax.fori_loop(0, n, lambda i, r: apply(pays[i], r), region)
+                acts0 = jax.eval_shape(apply, pays[0], region)[1]
+                nops = jnp.zeros((bucket, *acts0.shape), acts0.dtype).at[..., 0].set(A_NOP)
+
+                def step(i, carry):
+                    r, acts = carry
+                    nr, a = apply(pays[i], r)
+                    return nr, lax.dynamic_update_index_in_dim(acts, a, i, 0)
+
+                return lax.fori_loop(0, n, step, (region, nops))
 
             extra_avals = [a for i, a in enumerate(dep_avals) if i != rpos]
             fn, avals = folded, (block_aval, valid_aval, dep_avals[rpos], *extra_avals)
+            donate = (2,)
         else:
             def mapped(pays, *deps):
                 return lax.map(lambda p: call(p, *deps), pays)
 
             fn, avals = mapped, (block_aval, *dep_avals)
         with spans.span("pe/compile", bucket=bucket) if spans.enabled else spans.NULL:
-            compiled = jax.jit(named(fn, exe.name)).lower(*avals).compile()
+            compiled = jax.jit(named(fn, exe.name), donate_argnums=donate).lower(*avals).compile()
         self.stats.jit_ms_total += (time.perf_counter() - t0) * 1e3
         self.cache.install_batched(exe.digest, bucket, compiled)
         return compiled

@@ -122,14 +122,10 @@ class CompletionQueue:
         if tag is not None:
             self._tag_inflight[tag] = self._tag_inflight.get(tag, 0) + 1
             self._slot_tag[slot] = tag
-        arr = self.pe.region(self.region)
-        epoch = int(arr[slot, 1]) + 1
-        arr[slot, 0] = 0
-        arr[slot, 1] = epoch
-        arr[slot, 2:] = 0
-        # re-register so the device-resident copy the RETURN fold reads is
-        # refreshed with the new generation tag
-        self.pe.register_region(self.region, arr)
+        epoch = int(self.pe.region(self.region)[slot, 1]) + 1
+        # count and data cleared, the new generation tag stamped: on the
+        # device copy the RETURN fold reads too, without putting it back
+        self.pe.reset_row(self.region, slot, np.array([0, epoch], np.int32))
         tracer = getattr(getattr(self.pe, "fabric", None), "tracer", None)
         if tracer is not None:
             ev = {"src": getattr(self.pe, "name", ""), "slot": slot, "epoch": epoch}

@@ -11,8 +11,9 @@ code* and leaves only a fixed, function-agnostic action protocol in the
 runtime (the moral equivalent of the UCX API the paper's ifuncs link
 against):
 
-* ``update`` ABI — ``entry(payload, region) -> new_region``.  The runtime
-  stores the result back into the named memory region (TSI's counter).
+* ``update`` ABI — ``entry(payload, region) -> new_region``.  The result
+  becomes the named memory region's value, kept on the device (TSI's
+  counter); the region is donated, so a small write is made in place.
 * ``xrdma`` ABI — ``entry(payload, *linked_deps) -> i64[ACTION_WIDTH]``
   action vector::
 
@@ -29,8 +30,8 @@ against):
 * ``propagate`` ABI — ``entry(payload, region, *deps) -> (new_region,
   actions)``: one entry both folds into its linked region (like
   ``update``) *and* emits action rows (like ``xrdma``).  Under the
-  batched runtime the region fold is the same masked ``lax.scan`` as
-  ``update`` — which is exactly what a tree reduction needs: child
+  batched runtime the region fold is the same loop over the real payloads
+  as ``update`` — which is exactly what a tree reduction needs: child
   partials fold into the accumulator in one dispatch, and the row whose
   fold completes the subtree emits the upward FORWARD.
 
@@ -103,8 +104,7 @@ class Pending:
     """A dispatch whose outputs are still on their way to the host: what
     :meth:`ExecLayer.complete` needs to finish it."""
 
-    __slots__ = ("exe", "fn", "n", "batched", "host_args", "abi", "region", "reads",
-                 "vers", "out")
+    __slots__ = ("exe", "fn", "n", "batched", "host_args", "abi", "region", "out")
 
     def __init__(self, exe: CachedExecutable, fn, n: int, batched: bool,
                  host_args: list[np.ndarray]) -> None:
@@ -118,9 +118,7 @@ class Pending:
         if self.abi in ("update", "propagate"):
             self.region = dep_named(exe, "region")
             assert self.region is not None, f"{self.abi} ABI requires a region dep"
-        self.reads = [v for t, _, v in (d.partition(":") for d in exe.deps) if t == "region"]
-        self.vers: list[int] = []  # the versions of ``reads`` the dispatch saw
-        self.out = None
+        self.out = None  # the outputs still to reach the host
 
     def rows(self, out: np.ndarray) -> np.ndarray:
         """The real payloads' outputs of a batched dispatch."""
@@ -131,9 +129,9 @@ class ExecLayer:
     """Invoke + action application for one PE.
 
     ``rt`` is the runtime facade (:class:`repro.core.pe.pe.PE`): it links
-    dep arguments (regions as device-resident mirrors, capabilities),
-    stores update-ABI results back, collects DONE payloads, and carries
-    the travelling actions to the wire.
+    dep arguments (regions as their device values, capabilities), keeps
+    update-ABI results as their regions' values, collects DONE payloads,
+    and carries the travelling actions to the wire.
     """
 
     def __init__(self, rt, codecache: "CodeCacheLayer", stats, verifier=None) -> None:
@@ -223,20 +221,23 @@ class ExecLayer:
         with spans.span("pe/sync", bytes=nbytes) if spans.enabled else spans.NULL:
             return np.asarray(out)
 
-    def _versions(self, regions: list[str]) -> list[int]:
-        return [self.rt.endpoint.region_ver.get(r, 0) for r in regions]
-
     def _launch(self, p: "Pending") -> None:
-        """Dispatch ``p`` on the regions as they stand now, and start
-        copying its outputs to the host without waiting for them."""
+        """Dispatch ``p`` on the regions as they stand now and start copying
+        its outputs to the host without waiting for them.  An update or
+        propagate result becomes its region's device value here, so a
+        dispatch launched after this one reads it, and nothing is stale when
+        this one completes."""
         args = self._dep_args(p.exe)
-        p.vers = self._versions(p.reads)
-        if p.region is not None and p.batched:  # the masked scan takes the region first
+        if p.region is not None and p.batched:  # the fold takes the region first
             rpos = region_arg_pos(p.exe)
             args = [args[rpos], *(a for i, a in enumerate(args) if i != rpos)]
         out = self._dispatch(p.fn, *p.host_args, *args)
-        for leaf in out if isinstance(out, (tuple, list)) else (out,):
-            leaf.copy_to_host_async()
+        if p.region is not None:  # update: new_region; propagate: (new_region, actions)
+            new_region, out = (out, None) if p.abi == "update" else out
+            self.rt.write_region(p.region, new_region)
+        if out is not None:
+            for leaf in out if isinstance(out, (tuple, list)) else (out,):
+                leaf.copy_to_host_async()
         p.out = out
 
     def _begin_one(self, exe: CachedExecutable, payload: bytes) -> "Pending":
@@ -284,29 +285,19 @@ class ExecLayer:
 
     def _complete(self, p: "Pending") -> None:
         exe, abi = p.exe, p.abi
-        if self._versions(p.reads) != p.vers:
-            # a region changed after the dispatch read it (an earlier
-            # dispatch of this poll stored to it, or a one-sided RETURN
-            # landed): its result is stale, and storing it would overwrite
-            # those bytes, so dispatch again on the regions as they stand
-            self.stats.redispatches += 1
-            self._launch(p)
         if p.region is not None:
-            if abi == "propagate":
-                new_region, actions = p.out
-                self.rt.write_region(p.region, self._host(new_region))
-                # padded rows were masked to NOPs inside the scan; applying
-                # the real rows in payload order preserves the sequential
-                # semantics (the row that completes a fold emits the action)
-                self.apply_actions(exe, p.rows(self._host(actions)))
-            else:
-                self.rt.write_region(p.region, self._host(p.out))
-        elif abi == "xrdma":
+            self.rt.sync_watched(p.region)
+        if abi in ("xrdma", "propagate"):
+            # a propagate fold's padded rows emitted NOPs; applying the real
+            # rows in payload order preserves the sequential semantics (the
+            # row that completes a fold emits the action)
             self.apply_actions(exe, p.rows(self._host(p.out)))
-        elif p.batched:  # pure
-            self.rt.completed.extend(self._host(p.out)[: p.n])
-        else:
-            self.rt.completed.append(self._host(p.out))
+        elif abi == "pure":
+            out = self._host(p.out)
+            if p.batched:
+                self.rt.completed.extend(out[: p.n])
+            else:
+                self.rt.completed.append(out)
 
     def invoke(self, exe: CachedExecutable, payload: bytes) -> None:
         """Retire one payload: dispatch it and wait for it."""

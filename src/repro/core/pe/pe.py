@@ -6,8 +6,8 @@
   coalesced flush, rendezvous staging, per-peer credit windows.
 * :class:`repro.core.pe.codecache.CodeCacheLayer` — install arriving code,
   digest validation, bucketed batched executables.
-* :class:`repro.core.pe.exec.ExecLayer` — invoke, the masked-scan update
-  ABI, action application.
+* :class:`repro.core.pe.exec.ExecLayer` — invoke, the update-ABI fold,
+  action application.
 * :class:`repro.core.pe.progress.ProgressEngine` — the poll loop: priority
   lanes, per-poll budget, credit return.
 
@@ -21,9 +21,11 @@ capability/region linking — plus the source-side API (``send_ifunc``,
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import spans
@@ -43,6 +45,20 @@ from .source import IFunc, Toolchain
 from .wire import WireLayer
 
 
+RESET_BLOCK = 64  # rows a reset dispatch writes (padded)
+
+
+@partial(jax.jit, donate_argnums=0)
+def _reset_rows(region: jax.Array, rows: jax.Array, heads: jax.Array) -> jax.Array:
+    block = jnp.zeros((rows.shape[0], *region.shape[1:]), region.dtype)
+    return region.at[rows].set(block.at[:, : heads.shape[1]].set(heads), mode="drop")
+
+
+@jax.jit
+def _take(region: jax.Array, rows: jax.Array) -> jax.Array:
+    return jnp.take(region, rows, axis=0)
+
+
 @dataclass
 class PEStats:
     msgs: int = 0
@@ -51,9 +67,11 @@ class PEStats:
     batched_invokes: int = 0  # dispatches that retired >1 payload
     invoked_payloads: int = 0  # payloads retired across all dispatches
     h2d_bytes: int = 0  # regions put on the device + host arguments of dispatches
-    d2h_bytes: int = 0  # dispatch outputs copied to the host
+    d2h_bytes: int = 0  # dispatch outputs and pulled regions copied to the host
     overlapped_waits: int = 0  # dispatches completed while another PE's were in flight
-    redispatches: int = 0  # dispatches made again: a region they read changed in flight
+    device_stores: int = 0  # update results kept on the device as their region's value
+    region_pulls: int = 0  # regions brought to the host after a write on the device
+    region_pull_bytes: int = 0  # the bytes those pulls moved
     forwards: int = 0
     returns: int = 0
     spawns: int = 0
@@ -183,7 +201,11 @@ class PE:
         self.stats = PEStats()
         self.dataplane = DataPlaneConfig()  # protocol selection (default: framed)
         self.propagation = PropagationConfig()  # tree multicast policy
+        # region -> (host version it stands for, its value on the device)
         self._region_dev: dict[str, tuple[int, jax.Array]] = {}
+        self._watched: set[str] = set()  # regions host code reads: pulled on each write
+        self._resets: dict[str, dict[int, np.ndarray]] = {}  # region -> row -> head, queued
+        self.endpoint.pull = self._pull
         self._pub_seq = 0  # publish ids minted by this PE as a tree root
         # completion queues draining into this PE (quarantine sweeps them)
         self.completion_queues: list[CompletionQueue] = []
@@ -331,23 +353,44 @@ class PE:
                     fut.poison()
 
     # --- local state ------------------------------------------------------
-    def register_region(self, name: str, arr: np.ndarray) -> None:
-        self.endpoint.register_region(name, arr)
+    # A region has one authoritative copy at a time.  An update result stays
+    # on the device as the region's value (``write_region``) and the
+    # endpoint lists it ``device_ahead``; host code that reads the region
+    # pulls it (``region``, and every one-sided access through the
+    # endpoint).  Host bytes are put on the device once and cached until
+    # the host rewrites them (the endpoint's version).
+    def register_region(self, name: str, arr: np.ndarray | jax.Array) -> None:
+        """Register ``arr`` as region ``name``.  A device array stays where
+        it is as the region's value; its host bytes are pulled when host
+        code first reads them."""
+        self._resets.pop(name, None)
+        if not isinstance(arr, jax.Array):
+            self.endpoint.register_region(name, arr)
+            return
+        self.endpoint.register_region(name, np.empty(arr.shape, arr.dtype))
+        self._region_dev[name] = (
+            self.endpoint.region_ver[name], jax.device_put(arr, self.device))
+        self.endpoint.device_ahead.add(name)
 
     def region(self, name: str) -> np.ndarray:
-        return self.endpoint.regions[name]
+        """The region's bytes on the host.  From this call on they are kept
+        current: each write on the device is pulled as it completes, so host
+        code holding the array sees it."""
+        self._watched.add(name)
+        return self.endpoint.host_region(name)
 
     def region_device(self, name: str) -> jax.Array:
-        """Device-resident view of a region, cached until the region is
-        rewritten (read-mostly shards stay resident, like RDMA-registered
-        memory staying pinned).  Versioning lives on the endpoint so that
-        *remote* one-sided writes (zero-copy RETURNs landing in a slab)
-        also invalidate the device mirror — otherwise a framed fold could
-        read a stale snapshot and overwrite bytes the fabric just wrote."""
+        """The region's value on the device, with any queued row resets
+        applied: the value a dispatch left there, else the host bytes put
+        there and cached until the host rewrites them (like RDMA-registered
+        memory staying pinned).  The version lives on the endpoint so that
+        *remote* one-sided writes also invalidate the cached copy."""
         ver = self.endpoint.region_ver.get(name, 0)
         hit = self._region_dev.get(name)
-        if hit is not None and hit[0] == ver:
-            return hit[1]
+        if hit is not None and hit[0] == ver and not hit[1].is_deleted():
+            resets = self._resets.pop(name, None)
+            return self._apply_resets(name, hit[1], resets) if resets else hit[1]
+        self._resets.pop(name, None)  # the host bytes hold them
         host = self.endpoint.regions[name]
         self.stats.h2d_bytes += host.nbytes
         with spans.span("pe/h2d", bytes=host.nbytes) if spans.enabled else spans.NULL:
@@ -355,10 +398,84 @@ class PE:
         self._region_dev[name] = (ver, dev)
         return dev
 
-    def write_region(self, name: str, value: np.ndarray) -> None:
-        with spans.span("pe/write_region", bytes=value.nbytes) if spans.enabled else spans.NULL:
-            np.copyto(self.endpoint.regions[name], value)
-            self.endpoint.touch_region(name)
+    def write_region(self, name: str, value: jax.Array) -> None:
+        """Keep a dispatch's result on the device as the region's value; the
+        host bytes are pulled only when host code reads them."""
+        self.stats.device_stores += 1
+        with spans.span("pe/write_region", bytes=value.nbytes, region=name) if spans.enabled else spans.NULL:
+            self._region_dev[name] = (self.endpoint.region_ver.get(name, 0), value)
+            self.endpoint.device_ahead.add(name)
+            if name in self._watched:
+                value.copy_to_host_async()  # the pull at completion finds it there
+
+    def _pull(self, name: str) -> None:
+        """Bring a region's value from the device into its host array, in
+        place (the endpoint's ``pull``)."""
+        dev = self.region_device(name)
+        nbytes = dev.nbytes
+        self.stats.region_pulls += 1
+        self.stats.region_pull_bytes += nbytes
+        self.stats.d2h_bytes += nbytes
+        with spans.span("pe/sync", bytes=nbytes, region=name) if spans.enabled else spans.NULL:
+            host = np.asarray(dev)
+            buf = self.endpoint.regions[name]
+            if buf.flags.writeable:
+                np.copyto(buf, host)
+            else:
+                self.endpoint.regions[name] = host.copy()
+        self.endpoint.device_ahead.discard(name)
+
+    def sync_watched(self, name: str) -> None:
+        """After a write on the device completes: pull it if host code reads
+        this region (see :meth:`region`)."""
+        if name in self._watched:
+            self.endpoint.host_region(name)
+
+    def reset_row(self, name: str, row: int, head: np.ndarray) -> None:
+        """Row ``row`` of a 2-D region becomes ``head`` followed by zeros
+        (a completion-queue slot recycled).  Where the device holds the
+        region, the reset is queued for it (applied before its next
+        dispatch or pull), so the region is not put back on the device."""
+        ep = self.endpoint
+        hit = self._region_dev.get(name)
+        on_device = hit is not None and hit[0] == ep.region_ver.get(name, 0)
+        if name not in ep.device_ahead:  # the host bytes are current: keep them so
+            arr = ep.regions[name]
+            arr[row] = 0
+            arr[row, : len(head)] = head
+            if not on_device:
+                ep.touch_region(name)
+        if on_device:
+            self._resets.setdefault(name, {})[row] = np.asarray(head, ep.regions[name].dtype)
+
+    def _apply_resets(self, name: str, dev: jax.Array, resets: dict) -> jax.Array:
+        """Apply queued row resets to a region's device value, in blocks of
+        :data:`RESET_BLOCK` rows (one executable a region shape): the only
+        bytes of the region that cross to the device (``pe/h2d``)."""
+        items = list(resets.items())
+        width = len(items[0][1])
+        for lo in range(0, len(items), RESET_BLOCK):
+            block = items[lo : lo + RESET_BLOCK]
+            rows = np.full(RESET_BLOCK, dev.shape[0], np.int32)  # out of range: dropped
+            heads = np.zeros((RESET_BLOCK, width), dev.dtype)
+            rows[: len(block)] = [r for r, _ in block]
+            heads[: len(block)] = [h for _, h in block]
+            nbytes = rows.nbytes + heads.nbytes
+            self.stats.h2d_bytes += nbytes
+            with spans.span("pe/h2d", bytes=nbytes, rows=len(block)) if spans.enabled else spans.NULL:
+                dev = _reset_rows(dev, rows, heads)
+        self._region_dev[name] = (self._region_dev[name][0], dev)
+        return dev
+
+    def region_rows(self, name: str, rows: np.ndarray) -> np.ndarray:
+        """Rows of a 2-D region as it stands, taken where its newest bytes
+        are: on the device, a take of those rows alone, no whole-region pull."""
+        rows = np.asarray(rows, np.int32)
+        if name not in self.endpoint.device_ahead:
+            return self.endpoint.regions[name][rows]
+        pad = np.zeros(max(1, 1 << max(0, len(rows) - 1).bit_length()), np.int32)
+        pad[: len(rows)] = rows  # a power-of-two bucket: one executable per size class
+        return np.asarray(_take(self.region_device(name), pad))[: len(rows)]
 
     def register_cap(self, name: str, arr: np.ndarray) -> None:
         self.caps[name] = np.asarray(arr)

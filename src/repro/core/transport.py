@@ -22,7 +22,7 @@ import struct
 import threading
 from collections import deque
 from dataclasses import dataclass, field, replace
-from typing import Iterator, Sequence
+from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
@@ -348,13 +348,21 @@ class Endpoint:
     The receive queue models the ifunc message buffer the target polls; the
     regions dict models RDMA-registered memory exposed for one-sided GET/PUT
     (numpy arrays, addressable by (region_name, byte offset)).
+
+    A region whose newest bytes are on the device (an update result the
+    owning PE kept there) is listed in ``device_ahead``: every host access
+    here goes through :meth:`host_region`, which first has ``pull`` (set by
+    the owning PE) bring those bytes down, so there is one authoritative
+    copy at a time.
     """
 
     def __init__(self, name: str) -> None:
         self.name = name
         self.inbox: deque[bytearray] = deque()
         self.regions: dict[str, np.ndarray] = {}
-        self.region_ver: dict[str, int] = {}  # bumped on every (re)register/write
+        self.region_ver: dict[str, int] = {}  # bumped on every (re)register/host write
+        self.device_ahead: set[str] = set()  # regions whose newest bytes are on the device
+        self.pull: Callable[[str], None] | None = None  # brings them into ``regions``
         self.alive = True
         self._lock = threading.Lock()
 
@@ -367,23 +375,32 @@ class Endpoint:
         # in place (zero copy), preserving caller aliasing.
         self.regions[name] = np.ascontiguousarray(arr)
         self.region_ver[name] = self.region_ver.get(name, 0) + 1
+        self.device_ahead.discard(name)
 
     def touch_region(self, name: str) -> None:
-        """Record that a region's bytes changed underneath its registration
-        (local in-place mutation): device-resident mirrors must refresh."""
+        """Record that a region's host bytes changed underneath its
+        registration (local in-place mutation): device copies must refresh."""
         self.region_ver[name] = self.region_ver.get(name, 0) + 1
 
     def unregister_region(self, name: str) -> None:
         """Drop a registration and its version bookkeeping (rkey invalidated)."""
         self.regions.pop(name, None)
         self.region_ver.pop(name, None)
+        self.device_ahead.discard(name)
+
+    def host_region(self, name: str) -> np.ndarray:
+        """A region's bytes on the host, pulled from the device first where
+        the device holds newer ones."""
+        if name in self.device_ahead:
+            self.pull(name)
+        return self.regions[name]
 
     def read_region(self, region: str, offset: int, nbytes: int) -> bytes:
-        buf = self.regions[region].view(np.uint8).reshape(-1)
+        buf = self.host_region(region).view(np.uint8).reshape(-1)
         return bytes(buf[offset : offset + nbytes])
 
     def write_region(self, region: str, offset: int, data: bytes) -> None:
-        buf = self.regions[region].view(np.uint8).reshape(-1)
+        buf = self.host_region(region).view(np.uint8).reshape(-1)
         buf[offset : offset + len(data)] = np.frombuffer(data, np.uint8)
         self.touch_region(region)
 

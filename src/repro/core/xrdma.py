@@ -166,6 +166,7 @@ def make_gatherer(
     name: str = "gatherer",
     returns: str = "gather_return",
     pallas_tpu: bool = True,
+    dtype=jnp.float32,
 ) -> IFunc:
     """The X-RDMA Gather op: one hop of a sharded embedding/KV-row gather.
 
@@ -183,8 +184,9 @@ def make_gatherer(
       each key's *position* so every partial RETURN scatters into the
       right rows of the requester's slot (non-owned positions travel as
       -1), and
-    * RETURNs the resolved rows (bit-cast f32->i32, never converted) plus
-      their positions and a count to the requester's completion queue.
+    * RETURNs the resolved rows (bit-cast f32->i32, never converted; an
+      int32 shard's words as they are) plus their positions and a count to
+      the requester's completion queue.
 
     One action matrix of ``n_servers + 1`` rows covers every case: row
     ``s`` is the potential FORWARD to server ``s``, the last row the
@@ -195,6 +197,7 @@ def make_gatherer(
     K, D, S = n_keys, dim, n_servers
     if K > 31:
         raise ValueError("n_keys > 31 would overflow the i32 position bitmask")
+    words = jnp.dtype(dtype) == I32  # a word shard: its rows travel as they are
     ret_plen = 3 + K + K * D  # [slot, epoch, nres, pos(K), rows(K*D)]
     width = 3 + ret_plen  # rectangular action matrix; FORWARD rows zero-pad
 
@@ -209,9 +212,9 @@ def make_gatherer(
             mine = real & (loc >= 0) & (loc < rows_per)
             rows = resolve(shard, keys, lo)  # (K, D), zeros off-shard
             rows = jnp.where(mine[:, None], rows, jnp.zeros((), rows.dtype))
-            irows = lax.bitcast_convert_type(
-                rows.astype(jnp.float32), I32
-            ).reshape(-1)
+            if not words:
+                rows = lax.bitcast_convert_type(rows.astype(jnp.float32), I32)
+            irows = rows.reshape(-1)
             pos = jnp.arange(K, dtype=I32)
             nres = jnp.sum(mine.astype(I32))
             ret = jnp.concatenate(
@@ -258,7 +261,7 @@ def make_gatherer(
     fn_by_platform = None
     # the TPU slice carries the Pallas one-hot-MXU resolver when the shard
     # shape satisfies its blocking constraints (portable entry otherwise)
-    if pallas_tpu and (rows_per_shard <= 512 or rows_per_shard % 512 == 0):
+    if pallas_tpu and not words and (rows_per_shard <= 512 or rows_per_shard % 512 == 0):
         from repro.kernels.embed_lookup.kernel import embed_lookup
 
         def pallas_resolve(shard, keys, lo):
@@ -271,7 +274,7 @@ def make_gatherer(
         fn=entry_with(_take_rows),
         payload_aval=jax.ShapeDtypeStruct((GATHER_HDR + K,), I32),
         dep_avals=(
-            jax.ShapeDtypeStruct((rows_per_shard, D), jnp.float32),
+            jax.ShapeDtypeStruct((rows_per_shard, D), I32 if words else jnp.float32),
             jax.ShapeDtypeStruct((3,), I32),
         ),
         deps=("region:embed_shard", "cap:gather_meta", f"returns:{returns}"),
@@ -350,7 +353,7 @@ def make_gather_return(
     match the slot's current generation is a late result for a *retired*
     gather — dropped whole, so a recycled slot can never be corrupted by
     stale traffic.  Update-ABI, so a burst of partial returns folds into
-    the region in one masked-scan dispatch under the batched runtime.
+    the region in one dispatch under the batched runtime.
 
     Region row layout: ``[posmask, epoch, data(K*D)]``."""
     K, D = n_keys, dim
@@ -384,6 +387,61 @@ def make_gather_return(
         targets=targets,
         kind=kind,
         slab=_gather_slab(n_keys, dim, region),
+    )
+
+
+# -------------------------------------------------------------- KV update
+KV_UPDATE_HDR = GATHER_HDR + 2  # [requester, slot, epoch, key, field]
+
+
+def make_kv_update(
+    rows_per_shard: int,
+    fields: int,
+    field_words: int,
+    record_words: int,
+    returns: str = "gather_return",
+    targets: Sequence[str] = ("cpu-host", "cpu-bf2", "cpu-a64fx", "tpu-v5e"),
+    kind: FrameKind = FrameKind.BITCODE,
+    name: str = "kv_update",
+) -> IFunc:
+    """Write one field of one record where the record lives.
+
+    Payload ``[requester, slot, epoch, key, field, value(field_words)]``,
+    entering at the key's owner: the record's words ``field * field_words``
+    onwards become ``value`` (a ``dynamic_update_slice`` of the shard,
+    which the runtime donates, so the write is made in place).  One RETURN
+    in :func:`make_gather_return`'s format (``K = 1``, ``D =
+    record_words``) carries the record as it stands after the write, so
+    the acknowledgement is also a read of it.  Propagate ABI: the write and
+    the RETURN row come from one entry, and a batch of updates folds into
+    the shard in payload order in one dispatch."""
+    if fields * field_words > record_words:
+        raise ValueError("the fields do not fit the record")
+    W = record_words
+    ret_plen = 3 + 1 + W  # [slot, epoch, nres, pos, record]
+
+    def entry(payload: jax.Array, shard: jax.Array, meta: jax.Array):
+        requester, slot, epoch = payload[0], payload[1], payload[2]
+        key, field = payload[GATHER_HDR], payload[GATHER_HDR + 1]
+        row = key - meta[0] * meta[1]
+        shard = lax.dynamic_update_slice(
+            shard, payload[KV_UPDATE_HDR:][None, :], (row, field * field_words))
+        record = lax.dynamic_slice(shard, (row, jnp.asarray(0, I32)), (1, W)).reshape(-1)
+        head = jnp.stack([A_RETURN, requester, ret_plen, slot, epoch, 1, 0])
+        return shard, jnp.concatenate([head.astype(I32), record])[None, :]
+
+    return IFunc.build(
+        name=name,
+        fn=entry,
+        payload_aval=jax.ShapeDtypeStruct((KV_UPDATE_HDR + field_words,), I32),
+        dep_avals=(
+            jax.ShapeDtypeStruct((rows_per_shard, W), I32),
+            jax.ShapeDtypeStruct((3,), I32),
+        ),
+        deps=("region:embed_shard", "cap:gather_meta", f"returns:{returns}"),
+        abi="propagate",
+        targets=targets,
+        kind=kind,
     )
 
 

@@ -60,7 +60,7 @@ def ragged_batches(
 class GatherRequest:
     rid: int
     keys: np.ndarray  # (n,) int32 real keys, n <= n_keys
-    rows: np.ndarray | None = None  # (n, D) float32 result
+    rows: np.ndarray | None = None  # (n, D) result rows, in the table's dtype
     future: GatherFuture | None = None
     done: bool = False
     t_submit: float = 0.0
@@ -72,11 +72,6 @@ class GatherRequest:
     tenant: str | None = None  # whose credit budget the frames charge
     express: bool = False  # control-lane drain priority at the servers
     slot_quota: int = 0  # max CQ slots this tenant may hold (0 = uncapped)
-
-
-def _span_args(reqs: list[GatherRequest]) -> dict:
-    """A service span's arguments: how many requests, and which when one."""
-    return {"n": len(reqs), "rid": reqs[0].rid} if len(reqs) == 1 else {"n": len(reqs)}
 
 
 @dataclass
@@ -122,26 +117,35 @@ class EmbedShardService:
     ) -> None:
         if vocab % cluster.n_servers:
             raise ValueError("vocab must divide evenly across servers")
+        if table is None:
+            rng = np.random.default_rng(seed)
+            table = rng.standard_normal((vocab, dim)).astype(np.float32)
+        table = np.asarray(table)
+        # a word table keeps its int32 words; any other is served as float32
+        self.table = table if table.dtype == np.int32 else np.asarray(table, np.float32)
+        assert self.table.shape == (vocab, dim)
+        rows = vocab // cluster.n_servers
+        self._serve(
+            cluster, [self.table[i * rows : (i + 1) * rows].copy()
+                      for i in range(cluster.n_servers)],
+            n_keys, max_slots, strict_recovery,
+        )
+
+    def _serve(self, cluster: Cluster, shards: list, n_keys: int, max_slots: int,
+               strict_recovery: bool = False) -> None:
+        """Register one shard a server (host or device arrays, rows stay put
+        forever after), publish the operators and open the client's CQ."""
         self.cluster = cluster
-        self.vocab = vocab
-        self.dim = dim
+        self.rows_per_shard, self.dim = shards[0].shape
+        self.vocab = self.rows_per_shard * cluster.n_servers
+        self.dtype = np.dtype(shards[0].dtype)
         self.n_keys = n_keys
         self.max_slots = max_slots
         # strict_recovery: resubmit-budget exhaustion raises (after the
         # recovery sweep completes) instead of silently degrading
         self.strict_recovery = strict_recovery
-        self.rows_per_shard = vocab // cluster.n_servers
-        if table is None:
-            rng = np.random.default_rng(seed)
-            table = rng.standard_normal((vocab, dim)).astype(np.float32)
-        self.table = np.asarray(table, np.float32)
-        assert self.table.shape == (vocab, dim)
-        # shards + metadata to the servers (rows stay put forever after)
-        for i, pe in enumerate(cluster.servers):
-            lo = i * self.rows_per_shard
-            pe.register_region(
-                "embed_shard", self.table[lo : lo + self.rows_per_shard].copy()
-            )
+        for i, (pe, shard) in enumerate(zip(cluster.servers, shards)):
+            pe.register_region("embed_shard", shard)
             pe.register_cap(
                 "gather_meta",
                 np.array([i, self.rows_per_shard, cluster.n_servers], np.int32),
@@ -149,7 +153,7 @@ class EmbedShardService:
         # toolchain artifacts (code travels on first contact, then caches)
         self._publish_ops()
         self.cq = CompletionQueue(
-            cluster.client, shape=(n_keys, dim), dtype=np.float32,
+            cluster.client, shape=(n_keys, self.dim), dtype=self.dtype,
             max_slots=max_slots,
         )
         self.queue: deque[GatherRequest] = deque()
@@ -174,12 +178,21 @@ class EmbedShardService:
         """Publish this service's pushdown operator pair to the toolchain."""
         self.cluster.toolchain.publish(
             make_gatherer(
-                self.rows_per_shard, self.cluster.n_servers, self.n_keys, self.dim
+                self.rows_per_shard, self.cluster.n_servers, self.n_keys, self.dim,
+                dtype=self.dtype,
             )
         )
         self.cluster.toolchain.publish(
             make_gather_return(self.max_slots, self.n_keys, self.dim)
         )
+
+    def _op(self, req: GatherRequest) -> str:
+        """The ifunc a request is submitted as."""
+        return self.op_name
+
+    def _span_args(self, reqs: list[GatherRequest]) -> dict:
+        """A service span's arguments: how many requests, and which when one."""
+        return {"n": len(reqs), "rid": reqs[0].rid} if len(reqs) == 1 else {"n": len(reqs)}
 
     def _request_body(self, req: GatherRequest) -> np.ndarray:
         """The operator-specific request payload (appended after the
@@ -285,7 +298,7 @@ class EmbedShardService:
         with spans.span("svc/admit") if spans.enabled else spans.NULL as sp:
             admitted = self._admit_queued()
             if sp is not None:
-                sp.set(**_span_args(admitted))
+                sp.set(**self._span_args(admitted))
         return len(admitted)
 
     def _admit_queued(self) -> list[GatherRequest]:
@@ -301,7 +314,7 @@ class EmbedShardService:
                 # every owning shard is dead: nothing can serve any key —
                 # complete degraded with an all-invalid mask rather than
                 # submitting into a void
-                req.rows = np.zeros((len(req.keys), self.dim), np.float32)
+                req.rows = np.zeros((len(req.keys), self.dim), self.dtype)
                 req.valid = np.zeros(len(req.keys), bool)
                 req.degraded = True
                 req.done = True
@@ -311,7 +324,7 @@ class EmbedShardService:
                 continue
             fut = self.cluster.client.submit(
                 entry,
-                self.op_name,
+                self._op(req),
                 self._request_body(req),
                 self.cq,
                 expected=len(req.keys),
@@ -413,7 +426,7 @@ class EmbedShardService:
                     del self.active[slot]
                     retired.append(req)
             if sp is not None:
-                sp.set(**_span_args(retired))
+                sp.set(**self._span_args(retired))
         return len(retired)
 
     def tick(self) -> int:

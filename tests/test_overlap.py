@@ -103,8 +103,9 @@ def test_begin_leaves_a_dispatch_in_flight_and_poll_leaves_none():
 
 def test_one_sided_return_during_a_pending_fold_is_kept():
     """A framed RETURN's fold is in flight at the client when another
-    server's zero-copy RETURN lands in the same CQ slab: the fold sees the
-    slab change and folds again, so neither request's rows are lost."""
+    server's zero-copy RETURN lands in the same CQ slab: the fold's result
+    is already the slab's value, so the one-sided write pulls it and lands
+    on top; the fold runs once and neither request's rows are lost."""
     svc = make_service()
     cl = svc.cluster
     svc.gather([np.array([1, ROWS + 1], I32)], batching=True)  # warm both servers
@@ -118,12 +119,13 @@ def test_one_sided_return_during_a_pending_fold_is_kept():
                           svc.cq, expected=1)
     cl.client.flush()
     s0.poll()  # the framed RETURN reaches the client
-    redispatches = cl.client.stats.redispatches
+    invokes, pulls = cl.client.stats.invokes, cl.client.stats.region_pulls
     assert cl.client.poll_begin() == 1 and cl.client.in_flight == 1  # its fold in flight
     s1.poll()  # the one-sided RETURN writes B's row and doorbell into the slab
     assert cl.client.stats.zerocopy_returns == 0 and s1.stats.zerocopy_returns == 1
+    assert cl.client.stats.region_pulls == pulls + 1  # the fold's result, before the write
     cl.client.poll_complete()
-    assert cl.client.stats.redispatches == redispatches + 1
+    assert cl.client.stats.invokes == invokes + 1
     assert fa.done() and fb.done()
     np.testing.assert_array_equal(bits(fa.result()[:2]), bits(svc.table[[2, 3]]))
     np.testing.assert_array_equal(bits(fb.result()[:1]), bits(svc.table[[ROWS + 4]]))

@@ -12,6 +12,7 @@ import pytest
 from jax.profiler import TraceAnnotation
 
 from repro.core import Cluster, spans
+from repro.core.pe.pe import RESET_BLOCK
 from repro.runtime.embed_service import EmbedShardService, ragged_batches
 
 I32 = np.int32
@@ -72,8 +73,10 @@ def test_byte_counters_reckoned_by_hand(batching):
     """Two requests, all keys on server 0, after a warm-up that put the
     shard on the device.  Server 0 dispatches the gatherer (the payloads
     and its 3-word meta cap go up, one (S+1) x W action matrix a payload
-    comes back); the client folds each partial RETURN into its CQ slab,
-    which goes up (rewritten since the last fold) and comes back whole."""
+    comes back); the client resets the two recycled CQ slots on the device
+    (one block of row numbers and 2-word heads goes up), folds each partial
+    RETURN into its CQ slab, which stays on the device, and pulls the slab
+    after each fold."""
     svc = make_service()
     keys = [np.array([1, 2, 3], I32), np.array([4, 5, 6, 7], I32)]
     svc.gather(keys, batching=batching)
@@ -85,11 +88,12 @@ def test_byte_counters_reckoned_by_hand(batching):
     ret = (3 + K + K * DIM) * 4  # [slot, epoch, nres, pos(K), rows(K*D)]
     actions = (SERVERS + 1) * (3 + 3 + K + K * DIM) * 4
     slab = SLOTS * (2 + K * DIM) * 4
-    if batching:  # one dispatch a side: the gatherer's lax.map, the masked-scan fold
-        h2d = 2 * request + meta + 2 * ret + 2 + slab  # + the fold's 2-row valid mask
+    reset = RESET_BLOCK * (1 + 2) * 4  # row numbers and [count, epoch] heads
+    if batching:  # one dispatch a side: the gatherer's lax.map, the fold
+        h2d = 2 * request + meta + reset + 2 * ret + 2  # + the fold's 2-row valid mask
         d2h = 2 * actions + slab
     else:
-        h2d = 2 * (request + meta) + 2 * (ret + slab)
+        h2d = 2 * (request + meta) + reset + 2 * ret
         d2h = 2 * (actions + slab)
     assert (h1 - h0, d1 - d0) == (h2d, d2h)
 
