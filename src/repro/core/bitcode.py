@@ -18,11 +18,14 @@ import hashlib
 import io
 import struct
 from dataclasses import dataclass, field
+from functools import cached_property
+from types import MappingProxyType
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import jax.export
 
+from . import spans
 from .frame import CorruptFrame
 
 # Target triples. ``platform`` is what jax.export lowers for and which JAX
@@ -90,11 +93,18 @@ class BitcodeSlice:
         return hashlib.sha256(self.blob).hexdigest()
 
 
-@dataclass
+@dataclass(frozen=True)
 class FatBitcode:
-    """Archive of per-triple export blobs (paper Fig. 3 BITCODE fields)."""
+    """Archive of per-triple export blobs (paper Fig. 3 BITCODE fields).
 
-    slices: dict[str, bytes] = field(default_factory=dict)
+    Immutable once built: ``slices`` is a read-only mapping, so the wire
+    bytes and their digest are made once per archive, not once per frame
+    that carries it."""
+
+    slices: Mapping[str, bytes] = field(default_factory=dict)
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "slices", MappingProxyType(dict(self.slices)))
 
     # -- construction -------------------------------------------------------
     @classmethod
@@ -132,17 +142,32 @@ class FatBitcode:
         return cls(slices=slices)
 
     # -- the wire format ----------------------------------------------------
+    @cached_property
+    def _sealed(self) -> tuple[bytes, bytes]:
+        """The wire bytes and their sha256, made on first use (the one
+        ``pe/archive`` span of this archive)."""
+        with spans.span("pe/archive") if spans.enabled else spans.NULL as sp:
+            out = io.BytesIO()
+            out.write(_MAGIC)
+            out.write(struct.pack("<H", len(self.slices)))
+            for triple in sorted(self.slices):
+                blob = self.slices[triple]
+                t = triple.encode()
+                out.write(struct.pack("<HI", len(t), len(blob)))
+                out.write(t)
+                out.write(blob)
+            data = out.getvalue()
+            if sp is not None:
+                sp.set(bytes=len(data))
+            return data, hashlib.sha256(data).digest()
+
     def to_bytes(self) -> bytes:
-        out = io.BytesIO()
-        out.write(_MAGIC)
-        out.write(struct.pack("<H", len(self.slices)))
-        for triple in sorted(self.slices):
-            blob = self.slices[triple]
-            t = triple.encode()
-            out.write(struct.pack("<HI", len(t), len(blob)))
-            out.write(t)
-            out.write(blob)
-        return out.getvalue()
+        return self._sealed[0]
+
+    @property
+    def sha256(self) -> bytes:
+        """The raw sha256 of :meth:`to_bytes`: the archive's content address."""
+        return self._sealed[1]
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "FatBitcode":
@@ -195,7 +220,7 @@ class FatBitcode:
 
     @property
     def digest(self) -> str:
-        return hashlib.sha256(self.to_bytes()).hexdigest()
+        return self.sha256.hex()
 
     @property
     def nbytes(self) -> int:

@@ -20,7 +20,6 @@ Dependency tags (the wire ``DEPS`` list, Sec. III-C):
 
 from __future__ import annotations
 
-import hashlib
 from dataclasses import dataclass
 from typing import Any, Callable, Sequence
 
@@ -52,7 +51,7 @@ class IFunc:
 
     @property
     def digest(self) -> bytes:
-        return hashlib.sha256(self.code_bytes).digest()
+        return self.fat.sha256
 
     @classmethod
     def build(
