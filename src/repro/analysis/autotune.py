@@ -237,7 +237,7 @@ class ReplayModel:
     """Replays one captured trace under candidate knob settings.
 
     Expects a trace captured under the default runtime (per-message,
-    framed RETURNs) — what ``benchmarks/autotune.py`` records — and
+    framed RETURNs), as ``repro.analysis.capture`` records it, and
     estimates the fabric ``modeled_us`` a candidate :class:`FlowProfile`
     would produce for the same logical workload.  All aggregation follows
     fixed event order, so estimates are bit-deterministic.

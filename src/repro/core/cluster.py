@@ -5,8 +5,8 @@ peers[n_servers] is the client (host role).  This index space is what
 X-RDMA action vectors use for ``dst``/``requester`` fields.
 
 The scheduler is a deterministic single-threaded round-robin poll loop
-(this container has one core; daemon-thread polling is supported by the
-same PE.poll API but benchmarks use the scheduler for reproducibility).
+(daemon-thread polling is supported by the same PE.poll API, but the
+scheduler keeps every run reproducible).
 """
 
 from __future__ import annotations

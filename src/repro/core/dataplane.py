@@ -37,24 +37,18 @@ from typing import Callable, List
 
 import numpy as np
 
-from .frame import RNDV_DESC_NBYTES
-from .transport import RegionWrite, WireModel
+from .transport import RegionWrite
 
 #: Eager/zero-copy boundary: RETURN payloads at or below this many bytes
 #: stay framed (one coalesced alpha covers many of them); above it the
 #: payload is written one-sidedly into the completion slab.
 DEFAULT_EAGER_MAX = 256
 
-#: Framed-eager/rendezvous boundary, calibrated by benchmarks/wire_model.py:
-#: the crossover where a receive-side bounce copy at memcpy bandwidth costs
-#: more than the rendezvous round trip (~2*alpha*copy_bandwidth, tens of KB
-#: on every calibrated profile — the same order as UCX's default).
+#: Framed-eager/rendezvous boundary: about where a receive-side bounce copy
+#: at memcpy bandwidth costs more than the rendezvous round trip
+#: (~2*alpha*copy_bandwidth, tens of KB on every calibrated profile — the
+#: same order as UCX's default).
 DEFAULT_RNDV_MIN = 32 * 1024
-
-#: Receive-side copy bandwidth (bytes/us) charged against eager delivery in
-#: the crossover model only: an eager unexpected message lands in a bounce
-#: buffer and must be copied out; rendezvous and zero-copy land in place.
-COPY_BUS = 10_000.0
 
 
 @dataclass(frozen=True)
@@ -78,8 +72,8 @@ class DataPlaneConfig:
     """Per-PE protocol-selection thresholds (all sizes in payload bytes).
 
     The default is the pure framed plane (both fast paths disabled), which
-    is bit-compatible with the pre-dataplane runtime — benchmarks A/B the
-    three modes explicitly via the constructors below.
+    is bit-compatible with the pre-dataplane runtime; the constructors below
+    select each of the three modes explicitly.
     """
 
     eager_max: int = DEFAULT_EAGER_MAX
@@ -115,40 +109,3 @@ class DataPlaneConfig:
             return "rendezvous"
         return "framed"
 
-
-# ------------------------------------------------------- modeled cost table
-def framed_us(wire: WireModel, nbytes: int, hdr: int = 64, copy: bool = True) -> float:
-    """Eager framed delivery of one ``nbytes`` payload: wire latency plus
-    (optionally) the receive-side bounce copy real NICs pay for unexpected
-    eager messages."""
-    t = wire.latency_us(hdr + nbytes)
-    if copy:
-        t += nbytes / COPY_BUS
-    return t
-
-
-def zerocopy_us(wire: WireModel, nbytes: int) -> float:
-    """One-sided WRITE + 4-byte doorbell, landing in place (no copy)."""
-    return wire.latency_us(nbytes + 4)
-
-
-def rendezvous_us(wire: WireModel, nbytes: int, hdr: int = 64) -> float:
-    """Framed 16-byte descriptor + one GET round trip, landing in place."""
-    return wire.latency_us(hdr + RNDV_DESC_NBYTES) + 2 * wire.alpha_us + nbytes / wire.beta_Bus
-
-
-def eager_rndv_crossover(wire: WireModel, hdr: int = 64, max_bytes: int = 1 << 22) -> int:
-    """Smallest payload size where rendezvous beats framed eager delivery
-    (doubling + bisection over the monotone cost difference)."""
-    lo, hi = 1, 1
-    while hi < max_bytes and framed_us(wire, hi, hdr) <= rendezvous_us(wire, hi, hdr):
-        lo, hi = hi, hi * 2
-    if hi >= max_bytes:
-        return max_bytes
-    while lo + 1 < hi:
-        mid = (lo + hi) // 2
-        if framed_us(wire, mid, hdr) <= rendezvous_us(wire, mid, hdr):
-            lo = mid
-        else:
-            hi = mid
-    return hi

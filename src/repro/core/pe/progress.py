@@ -13,8 +13,7 @@ Two scheduling features beyond the flat FIFO drain:
   PUBLISH hop frames and rendezvous descriptors into the *control* lane,
   everything else (ifunc payloads, bulk RETURN data, AMs) into the *data*
   lane — and the control lane drains first.  Under overload a code
-  distribution no longer queues behind thousands of bulk RETURNs
-  (benchmarks/overload.py measures exactly this inversion).
+  distribution no longer queues behind thousands of bulk RETURNs.
 * **Poll budget** (``budget=N``): at most N *payloads* are processed per
   poll — a coalesced frame counts as its packed payload count, and a frame
   bigger than the remaining budget is consumed partially (the engine

@@ -26,8 +26,8 @@ spread across the PE layers:
 
 Everything defaults to *off*: ``ReliabilityConfig()`` is the pre-reliability
 runtime, bit-for-bit (frames carry seq 0 / ack 0 and bypass all of the
-above).  ``ReliabilityConfig.on()`` enables the layer with the defaults the
-benchmarks use.
+above).  ``ReliabilityConfig.on()`` enables the layer with the defaults
+below.
 """
 
 from __future__ import annotations

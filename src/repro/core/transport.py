@@ -8,7 +8,7 @@ GET reads a registered memory region *without running any code on the target*
 (one-sided semantics, the GBPC baseline relies on this).
 
 Every operation is additionally *accounted* against a calibrated wire model
-(:class:`WireModel`) so that benchmarks report a modeled wire time next to
+(:class:`WireModel`), so a run's reports carry a modeled wire time next to
 the measured in-process time.  The models are calibrated from the paper's own
 Tables I-III (two-point fit: cached 26 B frame and uncached 5185 B frame), so
 modeled cached/uncached and DAPC/GBPC *ratios* are directly comparable with
@@ -470,9 +470,9 @@ class Fabric:
         dropped with probability ``rate`` *after* the sender pays for it
         (bytes and modeled time are accounted — the NIC sent them; the
         receiver just never sees them, and no receive credit is consumed).
-        One mechanism shared by the chaos suites and
-        ``benchmarks/reliability.py``; the seeded generator makes every
-        loss schedule reproducible under the deterministic scheduler.
+        One mechanism shared by the chaos and reliability tests; the
+        seeded generator makes every loss schedule reproducible under the
+        deterministic scheduler.
         """
         if not 0.0 <= rate < 1.0:
             raise ValueError(f"loss rate {rate} outside [0, 1)")

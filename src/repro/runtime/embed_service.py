@@ -46,7 +46,7 @@ from repro.core.xrdma import (
 def ragged_batches(
     vocab: int, n_requests: int, n_keys: int, seed: int
 ) -> list[np.ndarray]:
-    """The canonical request mix for benchmarks/tests/examples: ``n_requests``
+    """The canonical request mix for tests and examples: ``n_requests``
     batches of 1..``n_keys`` uniform-random row ids (one shared definition so
     every consumer exercises the same workload shape)."""
     rng = np.random.default_rng(seed)

@@ -40,7 +40,7 @@ I32 = np.int32
 @pytest.fixture(scope="module")
 def captured():
     """One warm dapc run captured under the default runtime (the trace
-    shape ``benchmarks/autotune.py`` feeds the tuner)."""
+    shape the tuner expects)."""
     cl = Cluster(n_servers=4, wire="thor_xeon")
     app = PointerChaseApp(cl, n_entries=512, max_slots=16, seed=0)
     rng = np.random.default_rng(1)
@@ -210,7 +210,7 @@ def test_profile_apply_matches_set_flow(tmp_path):
 
 
 def test_tuned_profile_improves_live_run_oracle_identical(captured, tmp_path):
-    """The benchmark's claim at test scale: tune from the captured trace,
+    """The tuner's claim at test scale: tune from the captured trace,
     install through the disk loader, and the live tuned run beats the
     live default with bit-identical results."""
     rec, _ = captured

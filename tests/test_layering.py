@@ -8,7 +8,7 @@ Two things a refactor must never silently break:
   older notebooks depend on that surface;
 * no module outside ``repro.core.pe`` imports a private ``_``-prefixed
   symbol from a layer module (enforced by walking every AST in src/,
-  tests/, and benchmarks/), and the layers themselves only share their
+  tests/, and examples/), and the layers themselves only share their
   public surface with each other — the facade composes layers, nothing
   reaches around it.
 """
@@ -18,7 +18,7 @@ import importlib
 import pathlib
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
-SCAN_DIRS = ("src", "tests", "benchmarks", "examples")
+SCAN_DIRS = ("src", "tests", "examples")
 CORE_PACKAGE = REPO / "src" / "repro" / "core"
 PE_PACKAGE = CORE_PACKAGE / "pe"
 LAYER_MODULES = ("source", "wire", "codecache", "exec", "progress", "cq", "pe")

@@ -55,6 +55,12 @@ perm = rng.permutation(n); table = np.empty(n, np.int32); table[perm] = np.roll(
 starts = rng.integers(0, n, 32).astype(np.int32)
 got = np.asarray(dapc_shard_map(jnp.asarray(table), jnp.asarray(starts), 17, mesh))
 results["dapc"] = int(np.sum(got != chase_oracle(table, starts, 17)))
+# the GET-style baseline, its table sharded over the mesh as the chase's is
+from repro.sharding.compute_to_data import gbpc_reference
+gbpc = jax.jit(lambda t, s: gbpc_reference(t, s, 17, mesh),
+               in_shardings=(NamedSharding(mesh, P("model")), NamedSharding(mesh, P())))
+got = np.asarray(gbpc(jnp.asarray(table), jnp.asarray(starts)))
+results["gbpc"] = int(np.sum(got != chase_oracle(table, starts, 17)))
 
 # ---- 3b. gather shard_map == take oracle (the serving-shape sibling)
 from repro.sharding.compute_to_data import gather_ref, gather_shard_map
@@ -144,6 +150,10 @@ def test_moe_a2a_matches_scatter(multidev_results):
 
 def test_dapc_shard_map_matches_oracle(multidev_results):
     assert multidev_results["dapc"] == 0
+
+
+def test_gbpc_reference_matches_oracle(multidev_results):
+    assert multidev_results["gbpc"] == 0
 
 
 def test_gather_shard_map_matches_oracle(multidev_results):

@@ -144,6 +144,55 @@ def test_filter_matches_oracle_both_placements(filter_svc):
                 np.testing.assert_array_equal(got, w)
 
 
+@pytest.fixture(scope="module")
+def ab_cell():
+    """A warm hetero-priced filter service homed on a server triple, with
+    its optimizer and windows (built once per triple)."""
+    cells = {}
+
+    def get(triple):
+        if triple not in cells:
+            cl = Cluster(
+                n_servers=2, wire="thor_xeon", server_triple=triple,
+                hetero_wire=True,
+            )
+            svc = FilterShardService(cl, vocab=512, dim=96, window=24, seed=0)
+            los = svc.windows(8, seed=1)
+            svc.filter(los, 0.0, placement="pushdown")  # code moved, compiled
+            cells[triple] = (svc, PlacementOptimizer(cl), los)
+        return cells[triple]
+
+    return get
+
+
+@pytest.mark.parametrize("selectivity", [0.05, 0.25, 0.75])
+@pytest.mark.parametrize("triple", ["cpu-bf2", "cpu-host"])
+def test_optimizer_agrees_with_exhaustive_ab(ab_cell, triple, selectivity):
+    """Run both placements, each oracle-identical, and score each with the
+    fabric's hetero-priced wire time plus the per-message overheads and
+    scan time it does not meter: the optimizer picks the cheaper one (the
+    DPU pulls at 0.75, where the Xeon still pushes down)."""
+    svc, opt, los = ab_cell(triple)
+    caps = svc.cluster.capabilities()
+    client, server = caps["client"], caps["server0"]
+    n, operand = len(los), svc.n_keys * svc.dim * 4
+    thresh = svc.thresh_for_selectivity(selectivity)
+    want = svc.oracle_filter(los, thresh)
+    scored = {}
+    for arm in ("pushdown", "pull"):
+        rep = svc.filter(los, thresh, placement=arm)
+        for got, w in zip(rep.results, want):
+            np.testing.assert_array_equal(got, w)
+        if arm == "pushdown":
+            assert rep.puts == 2 * n  # one request and one RETURN frame each
+            extra = n * (client.o_us + server.o_us) + n * operand / server.scan_Bus
+        else:
+            extra = n * operand / client.scan_Bus
+        scored[arm] = rep.modeled_us + extra
+    winner = min(scored, key=scored.get)
+    assert svc.plan_with(opt, los).choice == winner
+
+
 def test_filter_wire_bytes_scale_with_selectivity(filter_svc):
     svc = filter_svc
     los = svc.windows(8, seed=3)

@@ -11,7 +11,7 @@ validity mask).
 
 The injection points are the same ones tests/test_fault_injection.py
 drives (the endpoint inbox, ``Fabric.kill``) plus the new seeded Bernoulli
-loss hook ``Fabric.set_loss`` the chaos suite and benchmarks share.
+loss hook ``Fabric.set_loss`` the chaos suite shares.
 """
 
 import numpy as np
@@ -236,6 +236,28 @@ class TestLossInjection:
         server.poll()
         assert fabric.stats.frames_lost == 0
         assert server.region("counter")[0] == 5
+
+    @pytest.mark.parametrize("rate", [0.01, 0.05])
+    def test_gather_exactly_once_under_seeded_loss(self, rate):
+        """At 1% and 5% seeded loss every gather retires exactly once, not
+        degraded (no owner died), its rows bit-identical to the take."""
+        cl = Cluster(n_servers=2, wire="ideal")
+        svc = EmbedShardService(cl, vocab=128, dim=8, n_keys=6, max_slots=16)
+        batches = ragged_batches(svc.vocab, 96, svc.n_keys, seed=5)
+        svc.gather(batches[:8])  # code and pad buckets in place, lossless
+        cl.set_reliability(ReliabilityConfig.on())
+        cl.fabric.set_loss(rate, seed=3)
+        n0 = len(svc.finished)
+        rids = [svc.submit(b) for b in batches]
+        svc.run()
+        done = svc.finished[n0:]
+        assert sorted(r.rid for r in done) == sorted(rids)
+        by_rid = {r.rid: r for r in done}
+        for rid, want in zip(rids, svc.oracle(batches)):
+            assert not by_rid[rid].degraded
+            np.testing.assert_array_equal(by_rid[rid].rows, want)
+        assert cl.fabric.stats.frames_lost > 0
+        assert sum(pe.stats.retransmits for pe in cl.pes()) > 0
 
 
 class TestFailureDetector:

@@ -10,7 +10,7 @@ degraded) — with **zero effect on benign traffic** sharing the fabric.
 
 The disabled path is equally load-bearing: with the default config no
 verification runs at all (``verifier.verifies == 0`` everywhere), which
-is what keeps the seven committed benchmark baselines reproducible.
+is what keeps the disabled runtime bit-for-bit the unsandboxed one.
 """
 
 import jax
@@ -328,6 +328,39 @@ class TestTtlCeiling:
         assert reminter.digest.hex() in srv.verifier.quarantined
         # the refused publish never travelled: server1 saw nothing
         assert cl.servers[1].region("gossip_log").tolist() == [0, 0]
+
+    def test_remint_is_quarantined_cluster_wide(self, reminter, tsi):
+        """Beside benign sends on the same fabric, the re-minter ends
+        refused at the mint, banished from every PE, spread nowhere and
+        refused on sight at re-entry; the benign counters stay exact."""
+        cl = gossip_cluster(reminter)
+        for pe in cl.servers:
+            pe.register_region("counter", np.zeros(1, I32))
+        cl.toolchain.publish(tsi)
+        cl.set_sandbox(SandboxConfig.on(max_publish_ttl=4))
+
+        def benign():
+            for pe in cl.servers:
+                cl.client.send_ifunc(pe.name, "tsi", np.array([5], I32))
+            cl.drain()
+
+        benign()
+        cl.client.send_ifunc("server0", "reminter", np.array([1, 5], I32))
+        with pytest.raises(SandboxViolation, match="ttl 9"):
+            cl.servers[0].poll()
+        cl.drain()
+        hexd = reminter.digest.hex()
+        for pe in cl.pes():
+            assert hexd in pe.verifier.quarantined
+            assert not pe.target_cache.has_name("reminter")
+        assert cl.servers[1].region("gossip_log").tolist() == [0, 0]
+        cl.client.send_ifunc("server1", "reminter", np.array([1, 5], I32))
+        with pytest.raises(SandboxViolation, match="quarantined"):
+            cl.servers[1].poll()
+        cl.drain()
+        benign()
+        assert counters(cl) == [10, 10]
+        assert cl.refusals()["verify_ttl"] == 1
 
     def test_remint_beyond_admitted_hop_ttl(self, reminter):
         """A PUBLISH-delivered slice is clamped to its *admitting hop's*
